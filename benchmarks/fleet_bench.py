@@ -1067,7 +1067,7 @@ def mobility():
         rung."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.api import engine as E
     from repro.core.mobility import MobilityModel, admit_mask_segmented
@@ -1079,7 +1079,7 @@ def mobility():
     reps = 3
     rng = np.random.default_rng(0)
     T = 1.2
-    with enable_x64():
+    with x64_scope():
         for n in sizes:
             n_servers = max(1, n // 16)
             S = 16 if n_servers % 16 == 0 else 1
@@ -1401,6 +1401,8 @@ ALL = [parity, warm_cold, scaling, speedup, rollout, sharded, chaos,
 
 
 def main():
+    from repro.core.types import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for fn in ALL:
         for name, us, derived in fn():

@@ -14,9 +14,11 @@ import numpy as np
 
 from repro.core import (OffloadInstance, amdp, amdp_hetero_comm, amr2,
                         brute_force, greedy_rra)
+from repro.core.types import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # ladder timings in the paper's range (Table II-like), identical jobs
     p_ed = np.array([0.010, 0.045])        # two ED models
     p_es = 0.35                            # comm + ES compute

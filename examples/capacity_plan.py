@@ -48,6 +48,8 @@ def sigmoid(x):
 
 
 def main() -> int:
+    from repro.core.types import enable_compile_cache
+    enable_compile_cache()
     import numpy as np
 
     import optax

@@ -105,6 +105,8 @@ def _main_rollout(args) -> None:
 
 
 def main(argv=None):
+    from repro.core.types import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=64)
     ap.add_argument("--periods", type=int, default=20)

@@ -36,6 +36,8 @@ sys.path.insert(0, os.path.join(REPO, "src"))
 
 
 def main() -> int:
+    from repro.core.types import enable_compile_cache
+    enable_compile_cache()
     import numpy as np
 
     from repro.api import engine as E

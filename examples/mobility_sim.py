@@ -18,10 +18,12 @@ import numpy as np
 
 from repro.api import engine as E
 from repro.core.mobility import MobilityModel
+from repro.core.types import enable_compile_cache
 from repro.serving import FleetConfig
 
 
 def main():
+    enable_compile_cache()
     D, periods = 64, 16
     cfg = FleetConfig(n_devices=D, T=1.2, n_servers=8, policy="amr2",
                       rate=9.0, batch_max=8, horizon=periods + 2, seed=0)

@@ -9,6 +9,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.core import paper_instance
+from repro.core.types import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import decode_step, init_params, prefill
 from repro.api import solve
@@ -16,6 +17,7 @@ from repro.optim import adamw_init
 
 
 def main():
+    enable_compile_cache()
     # 1. a reduced internlm2-family model (same code path as the 20B)
     cfg = get_smoke_config("internlm2_20b")
     key = jax.random.key(0)

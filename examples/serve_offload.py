@@ -25,6 +25,7 @@ from repro.launch.steps import make_train_step
 from repro.models import forward, init_params, logits_from_h
 from repro.optim import adamw_init
 from repro.api import solve
+from repro.core.types import enable_compile_cache
 from repro.serving import ServingRuntime, TierProfile, measure_latency
 
 
@@ -71,6 +72,7 @@ def make_apply(cfg, params):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=24)
     ap.add_argument("--periods", type=int, default=6)

@@ -36,7 +36,7 @@ around a pure state machine:
     scan plus scalar `psum`s for the metrics.  CPU-validated with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
-Everything runs in float64 (`jax.experimental.enable_x64` around every
+Everything runs in float64 (`core.types.x64_scope` around every
 public entry point, like `solve_lp_batch`), so `step` is bit-comparable
 with the host `FleetEngine.run_period` — which now *delegates* to the same
 jitted period core on the jax backend (see `serving.fleet`).
@@ -77,8 +77,9 @@ from ..core.lp import (_bucket_maxiter, simplex_batch_core,
 from ..core.mobility import (MobilityModel, admit_mask_pool,
                              admit_mask_segmented, route_cells,
                              validate_mobility)
-from ..core.problem import (ES_DISABLED_SENTINEL, ST_UNSOLVED as
-                            _ST_UNSOLVED, FleetProblem)
+from ..core.problem import (AUDIT_RTOL, ES_DISABLED_SENTINEL,
+                            ST_UNSOLVED as _ST_UNSOLVED, FleetProblem)
+from ..core.types import x64_scope
 
 # Policies with a fully-traceable batched path (the scan/shard requirement;
 # "auto"/"amdp" need host-side identical-job dispatch and stay on the host
@@ -783,7 +784,7 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
 
     Under ``axis_name`` (inside `shard_map`) the ES-pool admission runs on
     the `all_gather`-ed global demand vector and every metric scalar is
-    `psum`/`pmax`-reduced, so sharded and unsharded outputs agree.
+    `psum`- or max-reduced, so sharded and unsharded outputs agree.
 
     Mobility plumbing (all optional, None = single-pool semantics):
     ``es_belief`` (D, c) replaces `params.p_es` as the PRICED ES-latency
@@ -980,10 +981,15 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
 
     def _max(x):
         v = jnp.max(x, initial=0.0)
-        return jax.lax.pmax(v, axis_name) if axis_name else v
+        # the TPU lowers no float64 all-reduce but a sum: gather the
+        # per-shard maxima and reduce them locally instead of `pmax`
+        return (jnp.max(jax.lax.all_gather(v, axis_name)) if axis_name
+                else v)
 
     acc_jobs = params.acc[rows, assign]
     n_jobs = _sum(mask.astype(jnp.int32))
+    # the audits fire only past the threshold by more than rounding
+    audit_bar = params.straggler_threshold * (1.0 + AUDIT_RTOL)
 
     if hi_armed:
         # hierarchical: EVERY masked sample runs the local model (the
@@ -1033,7 +1039,7 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
         # less / demands more conservatively.  Null faults realize the
         # priced times bit for bit -> ratio == 1 -> no updates.
         es_ratio = rx.es_wall / jnp.maximum(es_wall, 1e-9)
-        es_upd = (es_wall > 0) & ((es_ratio > params.straggler_threshold)
+        es_upd = (es_wall > 0) & ((es_ratio > audit_bar)
                                   | (rx.n_dropped > 0))
         es_factor = (1.0 - params.ema) + params.ema * jnp.maximum(
             es_ratio, params.straggler_threshold)
@@ -1107,7 +1113,7 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
     viol = jnp.maximum(0.0, wall / params.T - 1.0)
 
     ratio = ed_audit / jnp.maximum(ed_pred, 1e-9)
-    upd = (ed_pred > 0) & (ratio > params.straggler_threshold)
+    upd = (ed_pred > 0) & (ratio > audit_bar)
     factor = (1.0 - params.ema) + params.ema * ratio
     new_belief = jnp.where(upd[:, None, None],
                            belief_p_ed * factor[:, None, None],
@@ -1327,7 +1333,7 @@ def _require_f64(tag: str, tree) -> None:
             raise TypeError(
                 f"{tag}.{f.name} is {dt} but the "
                 f"engine is float64-only; build arrays as float64 and do "
-                f"device transfers inside jax.experimental.enable_x64() "
+                f"device transfers inside repro.core.types.x64_scope() "
                 f"(with jax's global x64 mode off, an unscoped "
                 f"device_put downcasts to float32)")
 
@@ -1348,11 +1354,10 @@ def _check_horizon(state: EngineState, params: EngineParams,
 def step(state: EngineState, params: EngineParams
          ) -> Tuple[EngineState, PeriodMetrics]:
     """One jitted period transition (float64, like the host LP path)."""
-    from jax.experimental import enable_x64
     _require_f64("state", state)
     _require_f64("params", params)
     _check_horizon(state, params, 1)
-    with enable_x64():
+    with x64_scope():
         return _step_jit(state, params)
 
 
@@ -1367,12 +1372,11 @@ def rollout(state: EngineState, params: EngineParams, periods: int,
     caller must not reuse ``state`` afterwards) — at the 100k-device
     scale this halves peak memory, since the old and new fleet state
     never need to coexist."""
-    from jax.experimental import enable_x64
     _require_f64("state", state)
     _require_f64("params", params)
     _check_horizon(state, params, periods)
     fn = _rollout_donate if donate else _rollout_jit
-    with enable_x64():
+    with x64_scope():
         return fn(state, params, int(periods))
 
 
@@ -1478,9 +1482,8 @@ def rollout_value_and_grad(state: EngineState, params: EngineParams,
     the softened surrogate the finite-difference gates check).  Requires
     `EngineParams.with_differentiable`; sharded rollouts are not
     differentiable (run gradients on the single-host trace)."""
-    from jax.experimental import enable_x64
     leaf_vals, wrt = _grad_entry(state, params, periods, wrt)
-    with enable_x64():
+    with x64_scope():
         val, grads = _vag_jit(leaf_vals, state, params,
                               periods=int(periods), wrt=wrt)
     return val, dict(zip(wrt, grads))
@@ -1560,7 +1563,6 @@ def shard(state: EngineState, params: EngineParams, mesh
     `FleetProblem` is gathered from — lands block-partitioned along
     ``"fleet"``; scalars and class tables are replicated.  The fleet size
     must divide the mesh."""
-    from jax.experimental import enable_x64
     from jax.sharding import NamedSharding
     _reject_hi_sharded(params)
     _require_f64("state", state)
@@ -1572,7 +1574,7 @@ def shard(state: EngineState, params: EngineParams, mesh
             f"fleet size {D} does not divide the {n_shards}-device mesh")
     put = lambda tree, specs: jax.tree_util.tree_map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs)
-    with enable_x64():      # keep float64 leaves f64 across the device_put
+    with x64_scope():  # keep float64 leaves f64 across the device_put
         return put(state, _state_specs()), put(params, _param_specs(params))
 
 
@@ -1585,8 +1587,6 @@ def _sharded_fn(mesh, periods: Optional[int], params_aux: tuple,
     key because the in_specs pytree must carry the same aux as the actual
     params being passed; ``donate`` keys the variant that consumes the
     input state's buffers."""
-    from jax.experimental.shard_map import shard_map
-
     spec_params = _param_specs(
         EngineParams(**{f: None for f in _PARAM_LEAVES},
                      **dict(zip(_PARAM_AUX, params_aux))))
@@ -1597,11 +1597,11 @@ def _sharded_fn(mesh, periods: Optional[int], params_aux: tuple,
             return jax.lax.scan(
                 lambda s, _: _step_impl(s, params, axis_name=FLEET_AXIS),
                 state, None, length=periods)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(_state_specs(), spec_params),
         out_specs=(_state_specs(), _metric_specs()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())
 
 
@@ -1640,13 +1640,12 @@ def step_sharded(state: EngineState, params: EngineParams, mesh
     """`step` under `shard_map`: the fleet axis stays partitioned across
     the mesh; admission gathers the (D,) demand vector and metrics are
     psum-reduced, so the output matches the unsharded `step`."""
-    from jax.experimental import enable_x64
     _reject_diff_sharded(params)
     _reject_hi_sharded(params)
     _require_f64("state", state)
     _require_f64("params", params)
     _check_horizon(state, params, 1)
-    with enable_x64():
+    with x64_scope():
         return _sharded_fn(mesh, None, _aux_of(params))(state, params)
 
 
@@ -1656,12 +1655,11 @@ def rollout_sharded(state: EngineState, params: EngineParams,
     """`rollout` under `shard_map`: one scan, fleet axis sharded
     throughout — the ROADMAP's 10k+-device shape.  ``donate=True``
     consumes the input state's shards (see `rollout`)."""
-    from jax.experimental import enable_x64
     _reject_diff_sharded(params)
     _reject_hi_sharded(params)
     _require_f64("state", state)
     _require_f64("params", params)
     _check_horizon(state, params, periods)
-    with enable_x64():
+    with x64_scope():
         return _sharded_fn(mesh, int(periods), _aux_of(params),
                            donate)(state, params)

@@ -23,7 +23,7 @@ from ..core.greedy import greedy_rra
 from ..core.lp import INFEASIBLE, OPTIMAL, solve_lp_batch
 from ..core.problem import (ST_BOUND, SOLUTION_STATUS_NAMES, FleetProblem,
                             Problem, Solution)
-from ..core.types import next_pow2
+from ..core.types import next_pow2, x64_scope
 from .registry import register_solver
 
 _STATUS_CODE = {name: code for code, name in enumerate(SOLUTION_STATUS_NAMES)}
@@ -159,7 +159,6 @@ class _HISolverBase:
         ride on the returned Solution as ``sol.hi_state`` /
         ``sol.hi_theta``."""
         import jax as _jax
-        from jax.experimental import enable_x64
 
         from ..core.hi import (HILearnerState, HIModel, hi_period,
                                validate_hi)
@@ -185,7 +184,7 @@ class _HISolverBase:
         ces = (np.asarray(observed_es, bool) if have_obs
                else np.zeros((B, n), bool))
         acc_es = np.asarray(fleet.acc, np.float64)[:, m]
-        with enable_x64():
+        with x64_scope():
             key = _jax.random.fold_in(_jax.random.PRNGKey(seed),
                                       np.int32(t))
             offload, theta_t, new_hst, _reg = hi_period(

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .types import InstanceBatch, OffloadInstance, Schedule
+from .types import InstanceBatch, OffloadInstance, Schedule, x64_scope
 
 
 def _recover(inst: OffloadInstance, lam: float) -> np.ndarray:
@@ -163,8 +163,7 @@ def dual_schedule_batch_arrays(batch: InstanceBatch, *, iters: int = 40):
     follows the NumPy `dual_schedule` oracle exactly away from knapsack
     boundaries (see `_recover_jnp` on the summation-order caveat); parity
     tests assert identical assignments on random instances."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with x64_scope():
         assign, status = jax.tree_util.tree_map(
             np.asarray,
             _dual_batch_jit(jnp.asarray(batch.p_ed, jnp.float64),

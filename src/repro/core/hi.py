@@ -65,6 +65,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .types import x64_scope
+
 __all__ = [
     "HI_RULES", "HI_STREAMS", "EXP3_GAMMA",
     "HIModel", "HILearnerState",
@@ -292,9 +294,8 @@ def presample_stream(seed: int, n_devices: int, n_jobs: int,
     (fold the seed by period, split off the confidence key, fold global
     device ids).  Feeding it back via ``HIModel(conf_trace=...)`` +
     ``stream="replay"`` therefore pins replay == fold."""
-    from jax.experimental import enable_x64
     out = np.zeros((periods, n_devices, n_jobs, 3))
-    with enable_x64():
+    with x64_scope():
         base = jax.random.PRNGKey(seed)
         for t in range(periods):
             kc, _ka = jax.random.split(jax.random.fold_in(base, t))

@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .types import next_pow2
+from .types import next_pow2, x64_scope
 
 OPTIMAL, ITERATION_LIMIT, INFEASIBLE, UNBOUNDED = 0, 1, 2, 3
 
@@ -1157,7 +1157,6 @@ def solve_lp_batch(c, A_ub=None, b_ub=None, A_eq=None, b_eq=None, *,
     A, b, c_full, nv, _ = _canonicalize_batch(c, A_ub, b_ub, A_eq, b_eq)
     if maxiter is None:
         maxiter = _bucket_maxiter(50 * (A.shape[1] + 2))
-    from jax.experimental import enable_x64
     if method == "revised":
         basis0 = None
         if warm_basis is not None:
@@ -1167,7 +1166,7 @@ def solve_lp_batch(c, A_ub=None, b_ub=None, A_eq=None, b_eq=None, *,
                     f"warm_basis must be (B, R) = {A.shape[:2]}; "
                     f"got {wb.shape}")
             basis0 = jnp.asarray(wb)
-        with enable_x64():
+        with x64_scope():
             x, fun, status, niter, basis, ok = jax.tree_util.tree_map(
                 np.asarray,
                 _revised_batch_jit(jnp.asarray(A, jnp.float64),
@@ -1181,7 +1180,7 @@ def solve_lp_batch(c, A_ub=None, b_ub=None, A_eq=None, b_eq=None, *,
                              niter=np.asarray(niter, np.int64),
                              basis=np.asarray(basis, np.int64),
                              warm=np.asarray(ok, bool))
-    with enable_x64():
+    with x64_scope():
         if warm_basis is not None:
             wb = np.asarray(warm_basis, np.int64)
             if wb.shape != A.shape[:2]:

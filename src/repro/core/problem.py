@@ -44,6 +44,13 @@ ST_UNSOLVED = 4
 # `replan_without_es`.
 ES_DISABLED_SENTINEL = 1e9
 
+# Relative band an audit ratio must clear above the straggler threshold.
+# With ema = 0.5 a 3x drift lands the EMA exactly on the threshold (belief
+# 2x, ratio 3/2), so without the band the decision at that fixed point
+# follows the rounding of whichever program computed the ratio: sharded vs
+# unsharded, chip vs host, one compiler version vs the next.
+AUDIT_RTOL = 1e-9
+
 
 def _register_pytree(cls, fields: "tuple[str, ...]") -> None:
     """Register a frozen dataclass whose listed fields are all leaves.

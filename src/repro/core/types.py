@@ -17,6 +17,8 @@ means "offload to the ES".
 from __future__ import annotations
 
 import dataclasses
+import os
+from pathlib import Path
 from typing import Optional
 
 import numpy as np
@@ -32,6 +34,32 @@ def next_pow2(x: int) -> int:
     rounded up with this so fluctuating sizes reuse O(log) compiled
     programs instead of retracing per distinct value."""
     return 1 << (max(int(x), 1) - 1).bit_length()
+
+
+def x64_scope():
+    """Context manager that runs its body in float64 (``jax.enable_x64``).
+
+    Every float64 entry point (the LP/dual/HI solvers and the engine's
+    step, rollout and sharded entries) traces and transfers inside this
+    scope, so they stay float64 whatever jax's global x64 flag says."""
+    import jax
+    return jax.enable_x64(True)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself).  Otherwise the cache lives at ``<checkout>/.jax_cache``: a
+    fixed path, so a second run of the same program in the same checkout
+    finds the first run's executables.  Called from the scripts' and
+    examples' mains, never on import."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @dataclasses.dataclass(frozen=True)

@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -76,8 +75,8 @@ def pipeline_apply(fn: Callable, params_stacked, x, *, mesh: Mesh,
         return out.reshape(x_all.shape)
 
     spec_params = jax.tree.map(lambda _: P(stage_axis), params_stacked)
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(spec_params, P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(params_stacked, x)

@@ -2,14 +2,14 @@
 (so `amdp(..., impl="pallas")` drops in)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .cckp_dp import cckp_model_dp
 
 
 def model_dp(y: jnp.ndarray, p_i: int, a_i: float, n_steps: int):
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode("cckp_dp", y)
     a = jnp.asarray(a_i, jnp.float32)
     return cckp_model_dp(y, a, p=int(p_i), n_steps=int(n_steps),
                          interpret=interpret)

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams as _CompilerParams
-
 
 def _kernel(a_ref, b_ref, y_ref, h_ref, *, bs: int):
     s_idx = pl.program_id(2)
@@ -64,7 +62,7 @@ def rglru_scan_fwd(a, b, *, bs: int = 128, bw: int = 512,
         out_specs=pl.BlockSpec((1, bs, bw), lambda bb, w, s: (bb, s, w)),
         out_shape=jax.ShapeDtypeStruct((B, ns * bs, nw * bw), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -11,17 +11,9 @@ import jax
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """`jax.make_mesh` across jax versions: `AxisType`/`axis_types` only
-    exist on newer jax; older releases use Auto-equivalent semantics, so
-    omitting the kwarg there is behaviour-preserving."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis Auto (XLA propagates shardings)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

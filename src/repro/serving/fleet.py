@@ -53,8 +53,9 @@ from ..api import solve, solve_many
 from ..core.faults import FaultModel
 from ..core.instances import (PAPER_ACC, PAPER_COMM, PAPER_P_ED,
                               PAPER_P_ES_PROC)
-from ..core.problem import ES_DISABLED_SENTINEL, FleetProblem, Problem
-from ..core.types import OffloadInstance, Schedule
+from ..core.problem import (AUDIT_RTOL, ES_DISABLED_SENTINEL, FleetProblem,
+                            Problem)
+from ..core.types import OffloadInstance, Schedule, x64_scope
 from .profile import TierProfile, roofline_profile
 from .queue import RequestQueue
 from .runtime import audit_profile
@@ -539,8 +540,6 @@ class FleetEngine:
         replayed arrival trace (the same core scanned)."""
         import time as _time
 
-        from jax.experimental import enable_x64
-
         from ..api.engine import _period_jit
 
         t = self._period
@@ -575,7 +574,7 @@ class FleetEngine:
             warm = np.where((prev != outage)[:, None], np.int32(-1), warm)
 
         t0 = _time.perf_counter()
-        with enable_x64():
+        with x64_scope():
             fault_key = None
             if params.chaos:
                 # the exact per-period draw step() makes inside the scan:
@@ -778,7 +777,8 @@ class FleetEngine:
             n_viol += int((viol > 0).sum())
 
             ratio = ed_wall / np.maximum(ed_pred, 1e-9)
-            upd = (ed_pred > 0) & (ratio > self.straggler_threshold)
+            upd = (ed_pred > 0) & (ratio > self.straggler_threshold
+                                   * (1 + AUDIT_RTOL))
             if upd.any():
                 factor = (1 - self.ema) + self.ema * ratio
                 g.p_ed[upd] *= factor[upd, None, None]

@@ -15,6 +15,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from ..api import Problem, Solution, solve
+from ..core.problem import AUDIT_RTOL
 from ..core.types import OffloadInstance
 from .executor import ExecutionReport, execute
 from .profile import TierProfile
@@ -26,15 +27,16 @@ def audit_profile(profile: TierProfile, predicted_ed: float,
     """Shared straggler audit (single-device runtime AND fleet engine).
 
     When measured ED wall time drifts past ``threshold x`` the profile's
-    prediction, return a profile whose p_ed is EMA-rescaled toward the
-    observed slowdown: ``p_ed * ((1 - ema) + ema * ratio)``.
+    prediction (by more than the `AUDIT_RTOL` rounding band), return a
+    profile whose p_ed is EMA-rescaled toward the observed slowdown:
+    ``p_ed * ((1 - ema) + ema * ratio)``.
 
     Returns ``(profile, updated)``; the input profile is never mutated.
     """
     if predicted_ed <= 0:
         return profile, False
     ratio = measured_ed / max(predicted_ed, 1e-9)
-    if ratio <= threshold:
+    if ratio <= threshold * (1 + AUDIT_RTOL):
         return profile, False
     scaled = dataclasses.replace(
         profile, p_ed=profile.p_ed * ((1 - ema) + ema * ratio))

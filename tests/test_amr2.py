@@ -159,7 +159,7 @@ def test_round_relaxation_jnp_matches_numpy_batched():
     status codes — on real LP outputs across many instances."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.amr2 import (round_relaxation_batch,
                                  round_relaxation_jnp)
@@ -181,7 +181,7 @@ def test_round_relaxation_jnp_matches_numpy_batched():
     status[7] = ITERATION_LIMIT
     ref_assign, ref_status, ref_nf = round_relaxation_batch(
         batch, xbar, status, on_error="mark")
-    with enable_x64():
+    with x64_scope():
         got = jax.jit(round_relaxation_jnp)(
             jnp.asarray(batch.p_ed), jnp.asarray(batch.p_es),
             jnp.asarray(batch.acc), jnp.asarray(batch.T),
@@ -199,7 +199,7 @@ def test_round_relaxation_jnp_forced_fractional_rows():
     (including the infeasible-pair fallback)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.amr2 import (round_relaxation_batch,
                                  round_relaxation_jnp)
@@ -221,7 +221,7 @@ def test_round_relaxation_jnp_forced_fractional_rows():
     status = np.zeros(4, dtype=np.int64)
     ref_assign, ref_status, ref_nf = round_relaxation_batch(
         batch, xbar, status)
-    with enable_x64():
+    with x64_scope():
         got = jax.jit(round_relaxation_jnp)(
             jnp.asarray(batch.p_ed), jnp.asarray(batch.p_es),
             jnp.asarray(batch.acc), jnp.asarray(batch.T),

@@ -354,8 +354,8 @@ def test_admit_mask_matches_admit_and_traced_scan():
     np.testing.assert_allclose(mloads, loads, rtol=0, atol=0)
 
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    with enable_x64():
+    from repro.core.types import x64_scope
+    with x64_scope():
         jmask, jloads = E.admit_mask_jnp(jnp.asarray(dense, jnp.float64),
                                          jnp.float64(1.0), 3)
     np.testing.assert_array_equal(np.asarray(jmask), mask)
@@ -486,14 +486,14 @@ def test_plan_lane_chunking_is_bitwise_invisible(monkeypatch):
     import dataclasses
 
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.problem import FleetProblem
 
     cfg = _config(16, horizon=6)
     params = E.EngineParams.from_config(cfg, horizon=6)
     state = E.init_state(params)
-    with enable_x64():
+    with x64_scope():
         ci, take, *_ = E._arrivals(state, params)
         D, n = 16, params.batch_max
         mask = jnp.arange(n)[None, :] < take[:, None]

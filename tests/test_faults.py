@@ -97,14 +97,14 @@ def _fill_oracle(lat, accl, budget, elig):
 @given(seed=st.integers(0, 2**16))
 @settings(max_examples=10, deadline=None)
 def test_greedy_local_fill_matches_numpy_oracle(seed):
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
     rng = np.random.default_rng(seed)
     D, n, m = rng.integers(1, 5), rng.integers(1, 7), rng.integers(1, 4)
     lat = rng.uniform(0.05, 0.8, size=(D, n, m))
     accl = rng.uniform(0.2, 0.9, size=(D, m))
     budget = rng.uniform(0.0, 1.5, size=D)
     elig = rng.uniform(size=(D, n)) < 0.6
-    with enable_x64():
+    with x64_scope():
         choice, fit, used = greedy_local_fill(lat, accl, budget, elig)
     c0, f0, u0 = _fill_oracle(lat, accl, budget, elig)
     np.testing.assert_array_equal(np.asarray(choice), c0)
@@ -153,12 +153,12 @@ def test_ladder_invariants_hypothesis(seed, loss, crash, max_retries):
     fallback fits the residual deadline (ed_wall <= max(ed_audit, 2T)),
     (d) every admitted offload is accounted for exactly once, and (e)
     the pass is deterministic under a fixed key."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
     rng = np.random.default_rng(seed)
     fm = FaultModel.make(loss_rate=loss, es_crash_prob=crash,
                          link_degrade_prob=0.3, link_degrade_mag=0.5,
                          straggler_prob=0.3, straggler_mult=2.0)
-    with enable_x64():
+    with x64_scope():
         rx, real, demand, es_samp = _random_period(
             rng, fm, seed, max_retries=max_retries)
         rx2, *_ = _random_period(np.random.default_rng(seed), fm, seed,
@@ -186,9 +186,9 @@ def test_ladder_invariants_hypothesis(seed, loss, crash, max_retries):
 def test_null_realization_reproduces_priced_execution():
     """All-identity factors + no losses: the realized pass must equal the
     priced plan bit for bit (the armed-null engine pin relies on it)."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
     rng = np.random.default_rng(3)
-    with enable_x64():
+    with x64_scope():
         rx, real, demand, es_samp = _random_period(
             rng, FaultModel.none(), 3, max_retries=2)
     assert not bool(np.asarray(real.es_crash))
@@ -203,9 +203,9 @@ def test_null_realization_reproduces_priced_execution():
 def test_es_crash_skips_retries_and_walks_the_ladder():
     """A certain pool crash: no retry can help — zero retries, every
     offloaded sample lands on rung 2 or rung 3."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
     fm = FaultModel.make(es_crash_prob=1.0, loss_rate=0.0)
-    with enable_x64():
+    with x64_scope():
         rx, real, _, es_samp = _random_period(
             np.random.default_rng(0), fm, 0, max_retries=3)
     assert bool(np.asarray(real.es_crash))

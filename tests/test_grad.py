@@ -94,11 +94,11 @@ def test_lp_grad_forward_bitwise_matches_core(method):
     """simplex_batch_grad's forward pass IS simplex_batch_core — same
     pivots, same outputs, bit for bit (the VJP only attaches a backward
     rule)."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.lp import simplex_batch_core, simplex_batch_grad
     A, b, cf, nv = _canon(0)
-    with enable_x64():
+    with x64_scope():
         args = (jnp.asarray(A), jnp.asarray(b), jnp.asarray(cf), None)
         kw = dict(nv=nv, maxiter=200, method=method)
         ref = simplex_batch_core(*args, **kw)
@@ -109,7 +109,7 @@ def test_lp_grad_forward_bitwise_matches_core(method):
 
 def _lp_fd_probe(seed, n_probes=3, eps=1e-6):
     """FD-check d/d(b, c) of a random linear functional of (x, fun)."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.lp import OPTIMAL, simplex_batch_grad
     A, b, cf, nv = _canon(seed)
@@ -117,7 +117,7 @@ def _lp_fd_probe(seed, n_probes=3, eps=1e-6):
     wx = rng.normal(size=(A.shape[0], nv))
     wf = rng.normal(size=A.shape[0])
 
-    with enable_x64():
+    with x64_scope():
         def loss(b_, c_):
             x, fun, status, *_ = simplex_batch_grad(
                 jnp.asarray(A), b_, c_, None, nv=nv, maxiter=200)
@@ -160,12 +160,12 @@ def test_lp_implicit_vjp_matches_fd_hypothesis(seed):
 def test_lp_masked_lane_cotangents_zero():
     """Masked lanes carry garbage tableaus — their input cotangents must
     be EXACTLY zero, not NaN-contaminated."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.lp import simplex_batch_grad
     A, b, cf, nv = _canon(3)
     mask = np.array([True, False, True, False])
-    with enable_x64():
+    with x64_scope():
         def loss(b_):
             x, fun, *_ = simplex_batch_grad(
                 jnp.asarray(A), b_, jnp.asarray(cf), None, nv=nv,
@@ -181,11 +181,11 @@ def test_lp_grad_int_outputs_are_fences():
     """status/niter/basis outputs must yield float0/zero cotangents, and
     differentiating THROUGH them must not be attempted by jax (they are
     integer outputs — grad of the float outputs alone must trace)."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.lp import simplex_batch_grad
     A, b, cf, nv = _canon(5)
-    with enable_x64():
+    with x64_scope():
         # warm restart from the converged basis, THEN differentiate: the
         # basis0 int input gets a symbolic-zero cotangent internally.
         _, _, _, _, bases, _ = simplex_batch_grad(

@@ -107,13 +107,13 @@ def test_validate_hi_errors():
 def test_confidence_is_mean_preserving_and_calibrated():
     """E[conf] == acc_local and P(correct | conf) == conf (binned), for
     both tight and wide spreads; ES outcomes are Bernoulli(acc_es)."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
     D, n = 4, 20_000
     acc_local = np.array([0.55, 0.7, 0.8, 0.92])
     acc_es = np.array([0.9, 0.85, 0.95, 0.97])
     hm = HIModel.make(spread=0.8)
     ci = np.zeros((D, n), np.int32)
-    with enable_x64():
+    with x64_scope():
         conf, cl, ces = sample_confidence(
             jax.random.PRNGKey(3), hm, acc_local, acc_es, ci)
     conf, cl, ces = (np.asarray(x) for x in (conf, cl, ces))
@@ -127,7 +127,7 @@ def test_confidence_is_mean_preserving_and_calibrated():
             if sel.sum() > 500:
                 assert abs(cl[d, sel].mean() - conf[d, sel].mean()) < 0.05
     # spread really spreads: wider spread -> wider confidence swings
-    with enable_x64():
+    with x64_scope():
         conf0, _, _ = sample_confidence(
             jax.random.PRNGKey(3), HIModel.make(spread=0.1), acc_local,
             acc_es, ci)
@@ -137,9 +137,9 @@ def test_confidence_is_mean_preserving_and_calibrated():
 def test_draw_uniforms_gid_offset_matches_global_slice():
     """A shard drawing with its global-id offset reproduces exactly its
     rows of the full-fleet draw — the 8-shard-safe fold contract."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
     D, n, S = 4, 6, 3
-    with enable_x64():
+    with x64_scope():
         key = jax.random.PRNGKey(11)
         full = np.asarray(_draw_uniforms(key, S * D, n))
         for s in range(S):
@@ -152,10 +152,10 @@ def test_presample_stream_replays_the_fold_keyed_draws():
     """`presample_stream` must reproduce the armed engine's per-period
     uniforms bit for bit (fold seed by t, split off the confidence key,
     fold global device ids)."""
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
     tr = presample_stream(7, 3, 5, periods=4)
     assert tr.shape == (4, 3, 5, 3)
-    with enable_x64():
+    with x64_scope():
         base = jax.random.PRNGKey(7)
         for t in range(4):
             kc, _ = jax.random.split(jax.random.fold_in(base, t))

@@ -4,11 +4,11 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core.types import x64_scope
 from repro.kernels.cckp_dp.cckp_dp import cckp_model_dp
 from repro.kernels.cckp_dp.ref import cckp_model_dp_ref
 from repro.kernels.decode_attention.decode_attention import \
@@ -270,7 +270,7 @@ def _reduced_state(B, R, C0, seed):
     c_phase = np.zeros((B, C0))        # phase 1: artificials cost art_cost
     Binv = np.broadcast_to(np.eye(R), (B, R, R)).copy()
     basis = np.broadcast_to(C0 + np.arange(R, dtype=np.int32), (B, R)).copy()
-    with enable_x64():
+    with x64_scope():
         return tuple(jnp.asarray(x) for x in (A, c_phase, Binv, xB)) + (
             jnp.asarray(basis, jnp.int32),)
 
@@ -287,7 +287,7 @@ def test_reduced_pivot_kernel_vs_ref(B, R, C0):
     that.)  Masked lanes must pass through untouched, bit for bit."""
     from repro.kernels.simplex_pivot.ops import reduced_pivot
     from repro.kernels.simplex_pivot.ref import reduced_pivot_ref
-    with enable_x64():
+    with x64_scope():
         A, c_phase, Binv, xB, basis = _reduced_state(B, R, C0, B * 10 + C0)
         rng = np.random.default_rng(1)
         use_bland = jnp.asarray(rng.uniform(size=B) < 0.3)
@@ -314,7 +314,7 @@ def test_reduced_pivot_ref_maintains_basis_inverse():
     real label j <-> column A[:, j]) — i.e. the eta update is a genuine
     product-form basis-inverse update, not just a tableau transform."""
     from repro.kernels.simplex_pivot.ref import reduced_pivot_ref
-    with enable_x64():
+    with x64_scope():
         B, R, C0 = 6, 5, 12
         A, c_phase, Binv, xB, basis = _reduced_state(B, R, C0, 3)
         on = jnp.ones(B, bool)

@@ -276,13 +276,13 @@ def test_solve_lp_batch_warm_pallas_impl_matches_jnp():
 def _run_core(c, A_ub, b_ub, A_eq, b_eq, basis0, lane_mask=None):
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.lp import (_bucket_maxiter, _canonicalize_batch,
                                simplex_batch_core)
     A, b, cf, nv, _ = _canonicalize_batch(c, A_ub, b_ub, A_eq, b_eq)
     maxiter = _bucket_maxiter(50 * (A.shape[1] + 2))
-    with enable_x64():
+    with x64_scope():
         out = jax.jit(
             lambda A_, b_, c_: simplex_batch_core(
                 A_, b_, c_,
@@ -365,14 +365,14 @@ def test_simplex_batch_core_lane_mask_zeroes_masked_lanes():
 def _run_core_m(c, A_ub, b_ub, A_eq, b_eq, basis0, method, impl="jnp",
                 lane_mask=None, maxiter=None):
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.core.types import x64_scope
 
     from repro.core.lp import (_bucket_maxiter, _canonicalize_batch,
                                simplex_batch_core)
     A, b, cf, nv, _ = _canonicalize_batch(c, A_ub, b_ub, A_eq, b_eq)
     if maxiter is None:
         maxiter = _bucket_maxiter(50 * (A.shape[1] + 2))
-    with enable_x64():
+    with x64_scope():
         out = simplex_batch_core(
             jnp.asarray(A), jnp.asarray(b), jnp.asarray(cf),
             None if basis0 is None else jnp.asarray(basis0),

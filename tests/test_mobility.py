@@ -174,8 +174,8 @@ def _capture_step_inputs(monkeypatch, state, params):
         raise _Captured
 
     monkeypatch.setattr(E, "_period_impl", spy)
-    from jax.experimental import enable_x64
-    with enable_x64(), pytest.raises(_Captured):
+    from repro.core.types import x64_scope
+    with x64_scope(), pytest.raises(_Captured):
         E._step_impl(state, params)
     return captured
 

@@ -1,4 +1,4 @@
-"""The batched solvers run under LOCAL `jax.experimental.enable_x64` scopes
+"""The batched solvers run under LOCAL `repro.core.types.x64_scope` scopes
 so their results are float64 regardless of the process-global
 ``jax_enable_x64`` flag.  Toggling the global flag mid-process must neither
 change results nor trip stale-trace / dtype-mismatch errors — the jit
@@ -125,8 +125,8 @@ def test_engine_f32_guard_under_global_x64_off():
             raise SystemExit("f32 state was accepted silently")
 
         # the correct pattern still works: scoped transfers stay f64
-        from jax.experimental import enable_x64
-        with enable_x64():
+        from repro.core.types import x64_scope
+        with x64_scope():
             good = jax.tree.map(jax.device_put, state)
         st2, m = E.step(good, params)
         assert np.asarray(st2.p_ed).dtype == np.float64
