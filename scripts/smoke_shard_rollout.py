@@ -13,7 +13,7 @@ fault-injection subsystem with a replayed fault trace AND flip a quarter
 of the fleet's outage schedule mid-horizon — the stale-warm-basis guard
 and the per-device folded fault draws must both hold under sharding).
 Exits 1 on any parity failure — integer metrics (including the ladder
-counters) and the final pytree state must match exactly, float metrics
+and LP pivot counters) and the final pytree state must match exactly, float metrics
 to 1e-9 (per-shard partial sums + psum reassociate the float64
 reductions).
 """
@@ -80,12 +80,16 @@ def main() -> int:
 
     ladder_ints = ("n_offload_samples", "n_offload_ok", "n_deadline_miss",
                    "n_retries", "n_fallback_local", "n_dropped")
+    # the LP's pivot counters: each lane batch waits for its slowest lane
+    # on every shard, so the psum-ed counts equal the unsharded ones
+    lp_counts = ("lp_pivots", "lp_pivot_slots")
 
     # one sharded step vs unsharded
     u1, mu = E.step(state, params)
     s1, ms = E.step_sharded(sstate, sparams, mesh)
     for f in ("n_jobs", "n_violations", "n_offloading", "n_backpressured",
-              "n_outage", "n_straggler_updates", "backlog") + ladder_ints:
+              "n_outage", "n_straggler_updates", "backlog") + ladder_ints \
+            + lp_counts:
         check(f"step/{f}", getattr(ms, f), getattr(mu, f), exact=True)
     for f in ("total_accuracy", "worst_violation", "es_utilization",
               "realized_makespan"):
@@ -95,7 +99,7 @@ def main() -> int:
     uf, MU = E.rollout(state, params, periods)
     sf, MS = E.rollout_sharded(sstate, sparams, periods, mesh)
     for f in ("n_jobs", "n_violations", "n_offloading", "n_backpressured",
-              "n_outage", "backlog") + ladder_ints:
+              "n_outage", "backlog") + ladder_ints + lp_counts:
         check(f"rollout/{f}", getattr(MS, f), getattr(MU, f), exact=True)
     for f in ("total_accuracy", "realized_makespan"):
         check(f"rollout/{f}", getattr(MS, f), getattr(MU, f), exact=False)

@@ -41,6 +41,13 @@ public entry point, like `solve_lp_batch`), so `step` is bit-comparable
 with the host `FleetEngine.run_period` — which now *delegates* to the same
 jitted period core on the jax backend (see `serving.fleet`).
 
+The engine traces itself: each public entry point opens `jax.profiler`
+host spans (``repro.<entry>`` over ``repro.validate``, ``repro.horizon``,
+``repro.launch``; see `_entry_spans`), every op of a period carries one
+stage scope in its HLO ``op_name`` (see `_step_impl`), and
+`PeriodMetrics` counts the LP's pivots and the lockstep loop's pivot
+slots (``lp_pivots``, ``lp_pivot_slots``).
+
 Typical use::
 
     from repro.api import engine
@@ -55,6 +62,7 @@ structure is stable.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -603,6 +611,15 @@ class PeriodMetrics:
     n_hi_offloaded: jnp.ndarray
     n_hi_local_final: jnp.ndarray
     hi_regret: jnp.ndarray
+    # the LP's lockstep pivot loop: ``lp_pivots`` sums every solved lane's
+    # simplex pivots (primary plan plus replan); ``lp_pivot_slots`` sums,
+    # per lane batch (each lane chunk of the plan, and of the replan), the
+    # batch's largest pivot count times its lanes — the pivots the loop
+    # paid for, masked-out replan lanes counting as idle slots.  Their
+    # ratio is the pivot loop's lane use.  Exact zeros under
+    # ``policy="dual"`` and with HI armed.
+    lp_pivots: jnp.ndarray
+    lp_pivot_slots: jnp.ndarray
 
 
 _STATE_FIELDS = ("period", "key", "p_ed", "pending", "head", "warm_basis",
@@ -690,14 +707,21 @@ def admit_mask_jnp(demands, T, n_servers: int):
 _PLAN_LANE_CHUNK = int(os.environ.get("REPRO_PLAN_LANE_CHUNK", "1024"))
 
 
+def _lane_chunks(D: int) -> int:
+    """Number of lane chunks `_plan` splits a D-lane batch into (1: flat;
+    see `_PLAN_LANE_CHUNK`)."""
+    chunk = _PLAN_LANE_CHUNK
+    return 1 if not chunk or D <= chunk or D % chunk else D // chunk
+
+
 def _plan(params: EngineParams, fp: FleetProblem, warm_basis,
           lane_mask=None):
     """Chunked wrapper over `_plan_flat` (see `_PLAN_LANE_CHUNK`)."""
     D = fp.p_es.shape[0]
-    chunk = _PLAN_LANE_CHUNK
-    if not chunk or D <= chunk or D % chunk:
+    nc = _lane_chunks(D)
+    if nc == 1:
         return _plan_flat(params, fp, warm_basis, lane_mask)
-    nc = D // chunk
+    chunk = D // nc
 
     def resh(x):
         return x.reshape((nc, chunk) + x.shape[1:])
@@ -710,6 +734,23 @@ def _plan(params: EngineParams, fp: FleetProblem, warm_basis,
     return jax.tree.map(lambda x: x.reshape((D,) + x.shape[2:]), out)
 
 
+def _lockstep_slots(niter, axis_name: Optional[str] = None):
+    """Pivot slots the lockstep simplex loop paid for: per lane batch of
+    `_plan` (each lane chunk, or the whole flat batch), the batch's
+    largest per-lane pivot count times its lanes.  ``niter`` (D,) int32
+    per-lane pivots, zero on masked lanes (idle slots).  Under
+    ``axis_name`` a batch's largest count is taken over every shard (the
+    shards meet at the admission gather, so the slowest shard's loop sets
+    the period), which keeps the psum-ed count equal to the unsharded
+    one on a flat plan."""
+    D = niter.shape[0]
+    nc = _lane_chunks(D)
+    peak = niter.reshape(nc, D // nc).max(axis=1)
+    if axis_name:
+        peak = jnp.max(jax.lax.all_gather(peak, axis_name), axis=0)
+    return (jnp.sum(peak) * (D // nc)).astype(jnp.int32)
+
+
 def _plan_flat(params: EngineParams, fp: FleetProblem, warm_basis,
                lane_mask=None):
     """One traced batched solve of a (padded) `FleetProblem`.
@@ -717,39 +758,46 @@ def _plan_flat(params: EngineParams, fp: FleetProblem, warm_basis,
     amr2: warm-or-cold batched simplex + vectorized rounding — per-lane
     bit-comparable with the host `solve(..., policy="amr2")` dispatch.
     dual: the vmapped bisection (`core.dual._dual_one`).  Returns
-    ``(assignment (D, n) int32, status (D,) int32, basis (D, R) int32)``
-    — plus the LP relaxation ``xbar (D, n, m+1)`` as a fourth element
-    when the ``differentiable`` aux is armed (amr2 only): the smoothed
-    accuracy blend needs the fractional solution, and the solve routes
-    through `lp.simplex_batch_grad` so cotangents reach ``A/b/c`` via
-    the implicit KKT solve instead of dying at the pivot while_loop.
+    ``(assignment (D, n) int32, status (D,) int32, basis (D, R) int32,
+    niter (D,) int32)`` — ``niter`` each lane's simplex pivots (zero
+    under dual and on masked lanes) — plus the LP relaxation ``xbar (D,
+    n, m+1)`` as a fifth element when the ``differentiable`` aux is
+    armed (amr2 only): the smoothed accuracy blend needs the fractional
+    solution, and the solve routes through `lp.simplex_batch_grad` so
+    cotangents reach ``A/b/c`` via the implicit KKT solve instead of
+    dying at the pivot while_loop.  The LP (build + solve) runs under
+    the ``lp`` scope, the rounding under ``round``.
     """
     D, n = fp.p_es.shape
     m = fp.p_ed.shape[2]
     if params.policy == "amr2":
-        A, b, c_full = build_lp_arrays_jnp(fp.p_ed, fp.p_es, fp.acc, fp.T)
-        maxiter = params.maxiter if params.maxiter is not None else \
-            _bucket_maxiter(50 * (A.shape[1] + 2))
-        solve = simplex_batch_grad if params.differentiable \
-            else simplex_batch_core
-        x, _fun, st, _ni, basis, _ok = solve(
-            A, b, c_full, warm_basis, nv=n * (m + 1), maxiter=maxiter,
-            tol=params.tol, lane_mask=lane_mask,
-            method=params.lp_method)
-        xbar = x.reshape(D, n, m + 1)
-        assign, sched_status, _nf = round_relaxation_jnp(
-            fp.p_ed, fp.p_es, fp.acc, fp.T, xbar, st,
-            frac_tol=params.frac_tol)
+        with jax.named_scope("lp"):
+            A, b, c_full = build_lp_arrays_jnp(fp.p_ed, fp.p_es, fp.acc,
+                                               fp.T)
+            maxiter = params.maxiter if params.maxiter is not None else \
+                _bucket_maxiter(50 * (A.shape[1] + 2))
+            solve = simplex_batch_grad if params.differentiable \
+                else simplex_batch_core
+            x, _fun, st, niter, basis, _ok = solve(
+                A, b, c_full, warm_basis, nv=n * (m + 1), maxiter=maxiter,
+                tol=params.tol, lane_mask=lane_mask,
+                method=params.lp_method)
+            xbar = x.reshape(D, n, m + 1)
+        with jax.named_scope("round"):
+            assign, sched_status, _nf = round_relaxation_jnp(
+                fp.p_ed, fp.p_es, fp.acc, fp.T, xbar, st,
+                frac_tol=params.frac_tol)
         out = (assign.astype(jnp.int32), sched_status.astype(jnp.int32),
-               basis.astype(jnp.int32))
+               basis.astype(jnp.int32), niter.astype(jnp.int32))
         return out + (xbar,) if params.differentiable else out
-    # dual: no basis to carry; status 0 = ok / 1 = fallback (the shared
-    # SOLUTION_STATUS_NAMES codes)
+    # dual: no basis to carry, no pivots; status 0 = ok / 1 = fallback
+    # (the shared SOLUTION_STATUS_NAMES codes)
     assign, st = jax.vmap(partial(_dual_one, iters=params.iters))(
         fp.p_ed, fp.p_es, fp.acc, fp.T)
     basis = (jnp.asarray(warm_basis, jnp.int32) if warm_basis is not None
              else jnp.full((D, params.n_basis_rows), -1, jnp.int32))
-    return assign.astype(jnp.int32), st.astype(jnp.int32), basis
+    return (assign.astype(jnp.int32), st.astype(jnp.int32), basis,
+            jnp.zeros(D, jnp.int32))
 
 
 def _recover_unsolved(assign, unsolved, p_ed_jobs, mask, acc, T):
@@ -812,26 +860,29 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
     """
     D, _c, m = belief_p_ed.shape
     n = params.batch_max
-    mask = jnp.arange(n)[None, :] < take[:, None]
-    rows = jnp.arange(D)[:, None]
-    ci = jnp.clip(ci, 0, params.p_es.shape[1] - 1)
-    p_ed_jobs = jnp.where(mask[..., None], belief_p_ed[rows, ci], 0.0)
-    base_jobs = jnp.where(mask[..., None], params.base_p_ed[rows, ci], 0.0)
-    if covered is not None:
-        # out-of-coverage == ES link down for this period
-        outage_t = outage_t | ~covered
+    with jax.named_scope("arrivals"):
+        mask = jnp.arange(n)[None, :] < take[:, None]
+        rows = jnp.arange(D)[:, None]
+        ci = jnp.clip(ci, 0, params.p_es.shape[1] - 1)
+        p_ed_jobs = jnp.where(mask[..., None], belief_p_ed[rows, ci], 0.0)
+        base_jobs = jnp.where(mask[..., None], params.base_p_ed[rows, ci],
+                              0.0)
+        if covered is not None:
+            # out-of-coverage == ES link down for this period
+            outage_t = outage_t | ~covered
 
-    def _es_jobs(tbl):
-        e = jnp.where(mask, tbl[rows, ci], 0.0)
-        if link_factor is not None:
-            e = e * link_factor[:, None]
-        return jnp.where(outage_t[:, None] & mask, ES_DISABLED_SENTINEL, e)
+        def _es_jobs(tbl):
+            e = jnp.where(mask, tbl[rows, ci], 0.0)
+            if link_factor is not None:
+                e = e * link_factor[:, None]
+            return jnp.where(outage_t[:, None] & mask, ES_DISABLED_SENTINEL,
+                             e)
 
-    es_tbl = params.p_es if es_belief is None else es_belief
-    p_es_jobs = _es_jobs(es_tbl)
-    Tvec = jnp.broadcast_to(params.T, (D,))
-    fp = FleetProblem.from_arrays_unchecked(p_ed_jobs, p_es_jobs,
-                                            params.acc, Tvec, mask)
+        es_tbl = params.p_es if es_belief is None else es_belief
+        p_es_jobs = _es_jobs(es_tbl)
+        Tvec = jnp.broadcast_to(params.T, (D,))
+        fp = FleetProblem.from_arrays_unchecked(p_ed_jobs, p_es_jobs,
+                                                params.acc, Tvec, mask)
 
     # ---- plan the whole (local) fleet in one traced solve ---------------
     diff = params.differentiable and params.policy == "amr2"
@@ -842,40 +893,45 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
         # gate additionally offloads the low-confidence ones.  The LP
         # never runs — there is no accuracy table to plan from in the
         # online problem — so basis/unsolved are inert passthroughs.
-        lm = params.hi_local
-        acc_es_col = params.acc[:, m]
-        kc, ka = jax.random.split(hi_key)
-        uni = (jnp.take(params.hi.conf_trace,
-                        hi_t % params.hi.conf_trace.shape[0], axis=0)
-               if params.hi_stream == "replay" else None)
-        conf, correct_local, correct_es = sample_confidence(
-            kc, params.hi, params.acc[:, lm], acc_es_col, ci,
-            uniforms=uni, axis_name=axis_name)
-        offload_int, _theta_t, new_hi, _reg = hi_period(
-            params.hi_rule, params.hi, hi_state, conf, correct_local,
-            correct_es, mask, acc_es_col, hi_t, ka, params.hi_arms,
-            axis_name=axis_name)
-        assign = jnp.where(offload_int, jnp.int32(m),
-                           jnp.int32(lm)).astype(jnp.int32)
-        basis = (jnp.asarray(warm_basis, jnp.int32)
-                 if warm_basis is not None
-                 else jnp.full((D, params.n_basis_rows), -1, jnp.int32))
-        n_unsolved = jnp.zeros(D, jnp.int32)
+        with jax.named_scope("hi_gate"):
+            lm = params.hi_local
+            acc_es_col = params.acc[:, m]
+            kc, ka = jax.random.split(hi_key)
+            uni = (jnp.take(params.hi.conf_trace,
+                            hi_t % params.hi.conf_trace.shape[0], axis=0)
+                   if params.hi_stream == "replay" else None)
+            conf, correct_local, correct_es = sample_confidence(
+                kc, params.hi, params.acc[:, lm], acc_es_col, ci,
+                uniforms=uni, axis_name=axis_name)
+            offload_int, _theta_t, new_hi, _reg = hi_period(
+                params.hi_rule, params.hi, hi_state, conf, correct_local,
+                correct_es, mask, acc_es_col, hi_t, ka, params.hi_arms,
+                axis_name=axis_name)
+            assign = jnp.where(offload_int, jnp.int32(m),
+                               jnp.int32(lm)).astype(jnp.int32)
+            basis = (jnp.asarray(warm_basis, jnp.int32)
+                     if warm_basis is not None
+                     else jnp.full((D, params.n_basis_rows), -1, jnp.int32))
+            n_unsolved = jnp.zeros(D, jnp.int32)
+            pivots = slots = jnp.zeros((), jnp.int32)
         # an outage period needs no special-casing: the ES column prices
         # at the disabled sentinel, so intended offloads carry infeasible
         # demand, lose admission, and fall back local below
     else:
-        new_hi = hi_state
-        plan_out = _plan(params, fp, warm_basis)
-        assign, status, basis = plan_out[:3]
-        xbar = plan_out[3] if diff else None
-        unsolved_lane = status == _ST_UNSOLVED
-        n_unsolved = unsolved_lane.astype(jnp.int32)
-        # per-lane recovery: unsolved lanes fall back to a greedy
-        # local-only plan (no ES demand) instead of racing uncertified
-        # roundings into the admission scan
-        assign = _recover_unsolved(assign, unsolved_lane, p_ed_jobs, mask,
-                                   params.acc, params.T)
+        with jax.named_scope("plan"):
+            new_hi = hi_state
+            plan_out = _plan(params, fp, warm_basis)
+            assign, status, basis, niter = plan_out[:4]
+            xbar = plan_out[4] if diff else None
+            unsolved_lane = status == _ST_UNSOLVED
+            n_unsolved = unsolved_lane.astype(jnp.int32)
+            # per-lane recovery: unsolved lanes fall back to a greedy
+            # local-only plan (no ES demand) instead of racing uncertified
+            # roundings into the admission scan
+            assign = _recover_unsolved(assign, unsolved_lane, p_ed_jobs,
+                                       mask, params.acc, params.T)
+            pivots = jnp.sum(niter)
+            slots = _lockstep_slots(niter, axis_name)
 
     # ---- ES-pool admission on the GLOBAL demand vector ------------------
     # S=1 runs the one-cell fast path of the segmented admission
@@ -885,44 +941,45 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
     # sort/cumsum work, no O(D) sequential pass (core.mobility).  Under
     # `shard_by_cell` the all_gather is elided outright: each shard admits
     # its own cells locally and only the per-cell loads are psum-merged.
-    demand = jnp.where(mask & (assign == m), p_es_jobs, 0.0).sum(axis=1)
-    use_cells = params.mobility_mode != "off" and params.n_cells > 1
-    inc = None          # inclusive chain loads (the admission relaxation)
-    if axis_name is None:
-        if use_cells:
+    with jax.named_scope("admission"):
+        demand = jnp.where(mask & (assign == m), p_es_jobs, 0.0).sum(axis=1)
+        use_cells = params.mobility_mode != "off" and params.n_cells > 1
+        inc = None      # inclusive chain loads (the admission relaxation)
+        if axis_name is None:
+            if use_cells:
+                admitted, cloads = admit_mask_segmented(
+                    demand, cell, params.T, params.n_cells,
+                    params.servers_per_cell)
+            else:
+                admitted, loads, inc = admit_mask_pool(demand, params.T,
+                                                       params.n_servers)
+        elif use_cells and params.shard_by_cell:
             admitted, cloads = admit_mask_segmented(
                 demand, cell, params.T, params.n_cells,
                 params.servers_per_cell)
+            cloads = jax.lax.psum(cloads, axis_name)
+        elif use_cells:
+            demand_g = jax.lax.all_gather(demand, axis_name, tiled=True)
+            cell_g = jax.lax.all_gather(cell, axis_name, tiled=True)
+            admitted_g, cloads = admit_mask_segmented(
+                demand_g, cell_g, params.T, params.n_cells,
+                params.servers_per_cell)
+            idx = jax.lax.axis_index(axis_name)
+            admitted = jax.lax.dynamic_slice_in_dim(admitted_g, idx * D, D)
         else:
-            admitted, loads, inc = admit_mask_pool(demand, params.T,
-                                                   params.n_servers)
-    elif use_cells and params.shard_by_cell:
-        admitted, cloads = admit_mask_segmented(
-            demand, cell, params.T, params.n_cells,
-            params.servers_per_cell)
-        cloads = jax.lax.psum(cloads, axis_name)
-    elif use_cells:
-        demand_g = jax.lax.all_gather(demand, axis_name, tiled=True)
-        cell_g = jax.lax.all_gather(cell, axis_name, tiled=True)
-        admitted_g, cloads = admit_mask_segmented(
-            demand_g, cell_g, params.T, params.n_cells,
-            params.servers_per_cell)
-        idx = jax.lax.axis_index(axis_name)
-        admitted = jax.lax.dynamic_slice_in_dim(admitted_g, idx * D, D)
-    else:
-        demand_g = jax.lax.all_gather(demand, axis_name, tiled=True)
-        admitted_g, loads, _inc_g = admit_mask_pool(demand_g, params.T,
-                                                    params.n_servers)
-        idx = jax.lax.axis_index(axis_name)
-        admitted = jax.lax.dynamic_slice_in_dim(admitted_g, idx * D, D)
-    if use_cells:
-        cell_load_out = cloads.sum(axis=1)              # (S,) global
-        loads_total = jnp.sum(cloads)
-    else:
-        cell_load_out = jnp.sum(loads)[None]            # (1,)
-        loads_total = jnp.sum(loads)
-    offl = demand > 0
-    bumped = offl & ~admitted
+            demand_g = jax.lax.all_gather(demand, axis_name, tiled=True)
+            admitted_g, loads, _inc_g = admit_mask_pool(demand_g, params.T,
+                                                        params.n_servers)
+            idx = jax.lax.axis_index(axis_name)
+            admitted = jax.lax.dynamic_slice_in_dim(admitted_g, idx * D, D)
+        if use_cells:
+            cell_load_out = cloads.sum(axis=1)              # (S,) global
+            loads_total = jnp.sum(cloads)
+        else:
+            cell_load_out = jnp.sum(loads)[None]            # (1,)
+            loads_total = jnp.sum(loads)
+        offl = demand > 0
+        bumped = offl & ~admitted
 
     # ---- backpressure: lane-masked ES-disabled replan -------------------
     # Skipped entirely (lax.cond) on no-bump periods; otherwise known-cold
@@ -937,42 +994,50 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
         return FleetProblem.from_arrays_unchecked(
             p_ed_jobs, p_es_crippled, params.acc, Tvec, mask)
 
-    if hi_armed:
-        # backpressure under HI needs no second LP: a bumped device's
-        # intended offloads simply stay on the local model (the sample
-        # already ran it — hierarchical inference's graceful fallback)
-        assign = jnp.where(bumped[:, None] & mask, jnp.int32(params.hi_local),
-                           assign)
-    elif diff and axis_name is None:
-        # Differentiable mode: the smoothed admission gives EVERY
-        # offloader partial weight on its ES-disabled alternative, so the
-        # replan runs unconditionally (lane_mask widened from `bumped` to
-        # `offl`) — the hard assignment merge below still only reads the
-        # bumped lanes, so the hard forward numbers are unchanged.
-        bp4 = _plan(params, _bp_problem(), None, lane_mask=offl)
-        assign_bp, st_bp, _bas_bp, xbar_bp = bp4
-        unsolved_bp = bumped & (st_bp == _ST_UNSOLVED)
-        assign_bp = _recover_unsolved(assign_bp, unsolved_bp, p_ed_jobs,
-                                      mask, params.acc, params.T)
-        assign_pre = assign                     # primary plan, post-recovery
-        assign = jnp.where(bumped[:, None], assign_bp, assign)
-        n_unsolved = n_unsolved + unsolved_bp.astype(jnp.int32)
-    else:
-        def _replan(assign):
-            assign_bp, st_bp = _plan(
-                params, _bp_problem(), None,
-                lane_mask=bumped if params.policy == "amr2" else None)[:2]
-            unsolved_bp_lane = bumped & (st_bp == _ST_UNSOLVED)
-            assign_bp = _recover_unsolved(assign_bp, unsolved_bp_lane,
-                                          p_ed_jobs, mask, params.acc,
-                                          params.T)
-            return (jnp.where(bumped[:, None], assign_bp, assign),
-                    unsolved_bp_lane.astype(jnp.int32))
+    with jax.named_scope("replan"):
+        if hi_armed:
+            # backpressure under HI needs no second LP: a bumped device's
+            # intended offloads simply stay on the local model (the sample
+            # already ran it — hierarchical inference's graceful fallback)
+            assign = jnp.where(bumped[:, None] & mask,
+                               jnp.int32(params.hi_local), assign)
+        elif diff and axis_name is None:
+            # Differentiable mode: the smoothed admission gives EVERY
+            # offloader partial weight on its ES-disabled alternative, so
+            # the replan runs unconditionally (lane_mask widened from
+            # `bumped` to `offl`) — the hard assignment merge below still
+            # only reads the bumped lanes, so the hard forward numbers are
+            # unchanged.
+            bp5 = _plan(params, _bp_problem(), None, lane_mask=offl)
+            assign_bp, st_bp, _bas_bp, niter_bp, xbar_bp = bp5
+            unsolved_bp = bumped & (st_bp == _ST_UNSOLVED)
+            assign_bp = _recover_unsolved(assign_bp, unsolved_bp, p_ed_jobs,
+                                          mask, params.acc, params.T)
+            assign_pre = assign                 # primary plan, post-recovery
+            assign = jnp.where(bumped[:, None], assign_bp, assign)
+            n_unsolved = n_unsolved + unsolved_bp.astype(jnp.int32)
+            pivots = pivots + jnp.sum(niter_bp)
+            slots = slots + _lockstep_slots(niter_bp)
+        else:
+            def _replan(assign):
+                assign_bp, st_bp, _bas, niter_bp = _plan(
+                    params, _bp_problem(), None,
+                    lane_mask=bumped if params.policy == "amr2"
+                    else None)[:4]
+                unsolved_bp_lane = bumped & (st_bp == _ST_UNSOLVED)
+                assign_bp = _recover_unsolved(assign_bp, unsolved_bp_lane,
+                                              p_ed_jobs, mask, params.acc,
+                                              params.T)
+                return (jnp.where(bumped[:, None], assign_bp, assign),
+                        unsolved_bp_lane.astype(jnp.int32), niter_bp)
 
-        assign, unsolved_bp = jax.lax.cond(
-            bumped.any(), _replan,
-            lambda a: (a, jnp.zeros_like(n_unsolved)), assign)
-        n_unsolved = n_unsolved + unsolved_bp
+            assign, unsolved_bp, niter_bp = jax.lax.cond(
+                bumped.any(), _replan,
+                lambda a: (a, jnp.zeros_like(n_unsolved),
+                           jnp.zeros(D, jnp.int32)), assign)
+            n_unsolved = n_unsolved + unsolved_bp
+            pivots = pivots + jnp.sum(niter_bp)
+            slots = slots + _lockstep_slots(niter_bp, axis_name)
 
     # ---- pricing, violations, straggler audit ---------------------------
     def _sum(x):
@@ -986,28 +1051,29 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
         return (jnp.max(jax.lax.all_gather(v, axis_name)) if axis_name
                 else v)
 
-    acc_jobs = params.acc[rows, assign]
-    n_jobs = _sum(mask.astype(jnp.int32))
-    # the audits fire only past the threshold by more than rounding
-    audit_bar = params.straggler_threshold * (1.0 + AUDIT_RTOL)
+    with jax.named_scope("pricing"):
+        acc_jobs = params.acc[rows, assign]
+        n_jobs = _sum(mask.astype(jnp.int32))
+        # the audits fire only past the threshold by more than rounding
+        audit_bar = params.straggler_threshold * (1.0 + AUDIT_RTOL)
 
-    if hi_armed:
-        # hierarchical: EVERY masked sample runs the local model (the
-        # offloaded ones too), so the ED load prices the full batch at
-        # ``hi_local`` regardless of the final assignment
-        ed_pred = p_ed_jobs[..., params.hi_local].sum(axis=1)
-        ed_wall = base_jobs[..., params.hi_local].sum(axis=1) * drift_t
-    else:
-        on_ed = mask & (assign < m)
-        picked = jnp.clip(assign, 0, m - 1)[..., None]
-        ed_pred = jnp.where(
-            on_ed, jnp.take_along_axis(p_ed_jobs, picked, axis=2)[..., 0],
-            0.0).sum(axis=1)
-        ed_wall = jnp.where(
-            on_ed, jnp.take_along_axis(base_jobs, picked, axis=2)[..., 0],
-            0.0).sum(axis=1) * drift_t
-    es_wall = jnp.where(admitted, demand, 0.0)
-    es_samp = mask & (assign == m)       # admitted offloads (post-replan)
+        if hi_armed:
+            # hierarchical: EVERY masked sample runs the local model (the
+            # offloaded ones too), so the ED load prices the full batch at
+            # ``hi_local`` regardless of the final assignment
+            ed_pred = p_ed_jobs[..., params.hi_local].sum(axis=1)
+            ed_wall = base_jobs[..., params.hi_local].sum(axis=1) * drift_t
+        else:
+            on_ed = mask & (assign < m)
+            picked = jnp.clip(assign, 0, m - 1)[..., None]
+            ed_pred = jnp.where(
+                on_ed, jnp.take_along_axis(p_ed_jobs, picked, axis=2)[..., 0],
+                0.0).sum(axis=1)
+            ed_wall = jnp.where(
+                on_ed, jnp.take_along_axis(base_jobs, picked, axis=2)[..., 0],
+                0.0).sum(axis=1) * drift_t
+        es_wall = jnp.where(admitted, demand, 0.0)
+        es_samp = mask & (assign == m)       # admitted offloads (post-replan)
 
     # ---- realized execution (chaos): inject faults, walk the ladder -----
     # `params.chaos` is static aux, so the fault-free trace below is the
@@ -1015,137 +1081,149 @@ def _period_impl(belief_p_ed, warm_basis, ci, take, drift_t, outage_t,
     # every factor is exactly 1.0 / every mask empty, and the realized
     # quantities reproduce the priced ones bit for bit.
     if params.chaos:
-        real = sample_realization(fault_key, params.faults, D, n,
-                                  params.max_retries + 1,
-                                  axis_name=axis_name)
-        lat_local = base_jobs * (drift_t * real.straggler_factor
-                                 )[:, None, None]
-        # realized execution prices from the TRUE ES table — the audit's
-        # inflated belief steers planning/admission, not physics
-        true_es_jobs = p_es_jobs if es_belief is None \
-            else _es_jobs(params.p_es)
-        rx = realize_execution(
-            params.faults, real, mask=mask, es_samp=es_samp,
-            acc_jobs=acc_jobs, p_es_jobs=true_es_jobs, ed_wall=ed_wall,
-            lat_local=lat_local, acc=params.acc, T=params.T,
-            max_retries=params.max_retries)
-        total_acc = _sum(jnp.where(mask, rx.acc, 0.0))
-        wall = rx.wall
-        ed_audit = rx.ed_audit       # excl. fallback compute: the audit
-        #                              tracks per-op slowdown, not load
-        # chaos -> planner feedback: a device whose realized ES time blew
-        # past its priced demand (or whose offloads got dropped) has its
-        # ES-latency belief EMA-inflated, so next period's plan offloads
-        # less / demands more conservatively.  Null faults realize the
-        # priced times bit for bit -> ratio == 1 -> no updates.
-        es_ratio = rx.es_wall / jnp.maximum(es_wall, 1e-9)
-        es_upd = (es_wall > 0) & ((es_ratio > audit_bar)
-                                  | (rx.n_dropped > 0))
-        es_factor = (1.0 - params.ema) + params.ema * jnp.maximum(
-            es_ratio, params.straggler_threshold)
-        new_es_belief = jnp.where(es_upd[:, None],
-                                  es_tbl * es_factor[:, None], es_tbl)
-        ladder = {
-            "n_offload_samples": _sum(rx.n_offload),
-            "n_offload_ok": _sum(rx.n_offload_ok),
-            "n_deadline_miss": _sum(rx.n_deadline_miss),
-            "n_retries": _sum(rx.n_retries),
-            "n_fallback_local": _sum(rx.n_fallback_local),
-            "n_dropped": _sum(rx.n_dropped),
-            "n_es_audit_updates": _sum(es_upd.astype(jnp.int32)),
-        }
+        with jax.named_scope("ladder"):
+            real = sample_realization(fault_key, params.faults, D, n,
+                                      params.max_retries + 1,
+                                      axis_name=axis_name)
+            lat_local = base_jobs * (drift_t * real.straggler_factor
+                                     )[:, None, None]
+            # realized execution prices from the TRUE ES table — the
+            # audit's inflated belief steers planning/admission, not
+            # physics
+            true_es_jobs = p_es_jobs if es_belief is None \
+                else _es_jobs(params.p_es)
+            rx = realize_execution(
+                params.faults, real, mask=mask, es_samp=es_samp,
+                acc_jobs=acc_jobs, p_es_jobs=true_es_jobs, ed_wall=ed_wall,
+                lat_local=lat_local, acc=params.acc, T=params.T,
+                max_retries=params.max_retries)
+        with jax.named_scope("pricing"):
+            total_acc = _sum(jnp.where(mask, rx.acc, 0.0))
+            wall = rx.wall
+            ed_audit = rx.ed_audit   # excl. fallback compute: the audit
+            #                          tracks per-op slowdown, not load
+            # chaos -> planner feedback: a device whose realized ES time
+            # blew past its priced demand (or whose offloads got dropped)
+            # has its ES-latency belief EMA-inflated, so next period's
+            # plan offloads less / demands more conservatively.  Null
+            # faults realize the priced times bit for bit -> ratio == 1
+            # -> no updates.
+            es_ratio = rx.es_wall / jnp.maximum(es_wall, 1e-9)
+            es_upd = (es_wall > 0) & ((es_ratio > audit_bar)
+                                      | (rx.n_dropped > 0))
+            es_factor = (1.0 - params.ema) + params.ema * jnp.maximum(
+                es_ratio, params.straggler_threshold)
+            new_es_belief = jnp.where(es_upd[:, None],
+                                      es_tbl * es_factor[:, None], es_tbl)
+            ladder = {
+                "n_offload_samples": _sum(rx.n_offload),
+                "n_offload_ok": _sum(rx.n_offload_ok),
+                "n_deadline_miss": _sum(rx.n_deadline_miss),
+                "n_retries": _sum(rx.n_retries),
+                "n_fallback_local": _sum(rx.n_fallback_local),
+                "n_dropped": _sum(rx.n_dropped),
+                "n_es_audit_updates": _sum(es_upd.astype(jnp.int32)),
+            }
     else:
-        if diff and axis_name is None:
-            # ---- smoothed accuracy: the differentiable twin -------------
-            # Two discrete stages get relaxed: Algorithm-2 rounding
-            # (temperature-softened assignment weights over the LP
-            # relaxation) and first-fit admission (a sigmoid capacity
-            # test on each offloader's inclusive chain load `inc` — the
-            # EXACT value the hard first-fit compared against T).  Per
-            # device: accP from the primary plan, accBP from the
-            # ES-disabled replan, blended by the admission weight; the
-            # "st" mode forwards the HARD decisions (one-hot weights,
-            # boolean admission) and routes gradients through the soft
-            # ones, so served numbers match the hard path while the
-            # cotangents stay alive.
-            if params.smooth_mode == "st":
-                wP = straight_through_weights(xbar, assign_pre,
-                                              tau=params.smooth_tau)
-                wBP = straight_through_weights(xbar_bp, assign_bp,
-                                               tau=params.smooth_tau)
+        with jax.named_scope("pricing"):
+            if diff and axis_name is None:
+                # ---- smoothed accuracy: the differentiable twin ---------
+                # Two discrete stages get relaxed: Algorithm-2 rounding
+                # (temperature-softened assignment weights over the LP
+                # relaxation) and first-fit admission (a sigmoid capacity
+                # test on each offloader's inclusive chain load `inc` —
+                # the EXACT value the hard first-fit compared against T).
+                # Per device: accP from the primary plan, accBP from the
+                # ES-disabled replan, blended by the admission weight; the
+                # "st" mode forwards the HARD decisions (one-hot weights,
+                # boolean admission) and routes gradients through the soft
+                # ones, so served numbers match the hard path while the
+                # cotangents stay alive.
+                if params.smooth_mode == "st":
+                    wP = straight_through_weights(xbar, assign_pre,
+                                                  tau=params.smooth_tau)
+                    wBP = straight_through_weights(xbar_bp, assign_bp,
+                                                   tau=params.smooth_tau)
+                else:
+                    wP = soft_assignment_weights(xbar, tau=params.smooth_tau)
+                    wBP = soft_assignment_weights(xbar_bp,
+                                                  tau=params.smooth_tau)
+                accP = jnp.where(mask, jnp.einsum("dsi,di->ds", wP,
+                                                  params.acc),
+                                 0.0).sum(axis=1)
+                accBP = jnp.where(mask, jnp.einsum("dsi,di->ds", wBP,
+                                                   params.acc),
+                                  0.0).sum(axis=1)
+                adm_soft = jax.nn.sigmoid(
+                    (params.T + 1e-12 - inc) / (params.admit_tau * params.T))
+                if params.smooth_mode == "st":
+                    adm_use = adm_soft + jax.lax.stop_gradient(
+                        admitted.astype(adm_soft.dtype) - adm_soft)
+                else:
+                    adm_use = adm_soft
+                dev_acc = jnp.where(offl, adm_use * accP
+                                    + (1.0 - adm_use) * accBP, accP)
+                total_acc = jnp.sum(dev_acc)
+            elif hi_armed:
+                # expected served accuracy under perfect calibration: an
+                # admitted offload scores the ES accuracy, a locally-served
+                # sample its own confidence (E[correct | conf] == conf)
+                total_acc = _sum(jnp.where(
+                    mask, jnp.where(es_samp, acc_es_col[:, None], conf),
+                    0.0))
             else:
-                wP = soft_assignment_weights(xbar, tau=params.smooth_tau)
-                wBP = soft_assignment_weights(xbar_bp,
-                                              tau=params.smooth_tau)
-            accP = jnp.where(mask, jnp.einsum("dsi,di->ds", wP,
-                                              params.acc), 0.0).sum(axis=1)
-            accBP = jnp.where(mask, jnp.einsum("dsi,di->ds", wBP,
-                                               params.acc), 0.0).sum(axis=1)
-            adm_soft = jax.nn.sigmoid(
-                (params.T + 1e-12 - inc) / (params.admit_tau * params.T))
-            if params.smooth_mode == "st":
-                adm_use = adm_soft + jax.lax.stop_gradient(
-                    admitted.astype(adm_soft.dtype) - adm_soft)
-            else:
-                adm_use = adm_soft
-            dev_acc = jnp.where(offl, adm_use * accP
-                                + (1.0 - adm_use) * accBP, accP)
-            total_acc = jnp.sum(dev_acc)
-        elif hi_armed:
-            # expected served accuracy under perfect calibration: an
-            # admitted offload scores the ES accuracy, a locally-served
-            # sample its own confidence (E[correct | conf] == conf)
-            total_acc = _sum(jnp.where(
-                mask, jnp.where(es_samp, acc_es_col[:, None], conf), 0.0))
+                total_acc = _sum(jnp.where(mask, acc_jobs, 0.0))
+            wall = jnp.maximum(ed_wall, es_wall)
+            ed_audit = ed_wall
+            new_es_belief = es_tbl
+            n_off = _sum(es_samp.astype(jnp.int32))
+            zero = jnp.zeros((), jnp.int32)
+            ladder = {
+                "n_offload_samples": n_off, "n_offload_ok": n_off,
+                "n_deadline_miss": zero, "n_retries": zero,
+                "n_fallback_local": zero, "n_dropped": zero,
+                "n_es_audit_updates": zero,
+            }
+    with jax.named_scope("pricing"):
+        viol = jnp.maximum(0.0, wall / params.T - 1.0)
+
+        ratio = ed_audit / jnp.maximum(ed_pred, 1e-9)
+        upd = (ed_pred > 0) & (ratio > audit_bar)
+        factor = (1.0 - params.ema) + params.ema * ratio
+        new_belief = jnp.where(upd[:, None, None],
+                               belief_p_ed * factor[:, None, None],
+                               belief_p_ed)
+        new_warm = basis if params.policy == "amr2" else warm_basis
+
+        metrics = {
+            "n_jobs": n_jobs,
+            "total_accuracy": total_acc,
+            "n_violations": _sum((viol > 0).astype(jnp.int32)),
+            "worst_violation": _max(viol),
+            "n_offloading": _sum(offl.astype(jnp.int32)),
+            "n_backpressured": _sum(bumped.astype(jnp.int32)),
+            "n_outage": _sum(outage_t.astype(jnp.int32)),
+            "n_straggler_updates": _sum(upd.astype(jnp.int32)),
+            "n_unsolved": _sum(n_unsolved),
+            "es_utilization": loads_total / (params.n_servers * params.T),
+            "realized_makespan": _max(wall),
+            "lp_pivots": _sum(pivots),
+            "lp_pivot_slots": _sum(slots),
+            **ladder,
+        }
+        if hi_armed:
+            metrics.update(
+                n_hi_offloaded=_sum(es_samp.astype(jnp.int32)),
+                n_hi_local_final=_sum((mask & (assign != m)
+                                       ).astype(jnp.int32)),
+                hi_regret=_sum(new_hi.cum_regret))
         else:
-            total_acc = _sum(jnp.where(mask, acc_jobs, 0.0))
-        wall = jnp.maximum(ed_wall, es_wall)
-        ed_audit = ed_wall
-        new_es_belief = es_tbl
-        n_off = _sum(es_samp.astype(jnp.int32))
-        zero = jnp.zeros((), jnp.int32)
-        ladder = {
-            "n_offload_samples": n_off, "n_offload_ok": n_off,
-            "n_deadline_miss": zero, "n_retries": zero,
-            "n_fallback_local": zero, "n_dropped": zero,
-            "n_es_audit_updates": zero,
-        }
-    viol = jnp.maximum(0.0, wall / params.T - 1.0)
-
-    ratio = ed_audit / jnp.maximum(ed_pred, 1e-9)
-    upd = (ed_pred > 0) & (ratio > audit_bar)
-    factor = (1.0 - params.ema) + params.ema * ratio
-    new_belief = jnp.where(upd[:, None, None],
-                           belief_p_ed * factor[:, None, None],
-                           belief_p_ed)
-    new_warm = basis if params.policy == "amr2" else warm_basis
-
-    metrics = {
-        "n_jobs": n_jobs,
-        "total_accuracy": total_acc,
-        "n_violations": _sum((viol > 0).astype(jnp.int32)),
-        "worst_violation": _max(viol),
-        "n_offloading": _sum(offl.astype(jnp.int32)),
-        "n_backpressured": _sum(bumped.astype(jnp.int32)),
-        "n_outage": _sum(outage_t.astype(jnp.int32)),
-        "n_straggler_updates": _sum(upd.astype(jnp.int32)),
-        "n_unsolved": _sum(n_unsolved),
-        "es_utilization": loads_total / (params.n_servers * params.T),
-        "realized_makespan": _max(wall),
-        **ladder,
-    }
-    if hi_armed:
-        metrics.update(
-            n_hi_offloaded=_sum(es_samp.astype(jnp.int32)),
-            n_hi_local_final=_sum((mask & (assign != m)
-                                   ).astype(jnp.int32)),
-            hi_regret=_sum(new_hi.cum_regret))
-    else:
-        metrics.update(n_hi_offloaded=jnp.zeros((), jnp.int32),
-                       n_hi_local_final=jnp.zeros((), jnp.int32),
-                       hi_regret=jnp.zeros((), jnp.float64))
-    return (new_belief, new_warm.astype(jnp.int32), upd, factor,
-            new_es_belief, cell_load_out, new_hi, metrics)
+            metrics.update(n_hi_offloaded=jnp.zeros((), jnp.int32),
+                           n_hi_local_final=jnp.zeros((), jnp.int32),
+                           hi_regret=jnp.zeros((), jnp.float64))
+        new_warm = new_warm.astype(jnp.int32)
+    return (new_belief, new_warm, upd, factor, new_es_belief, cell_load_out,
+            new_hi, metrics)
 
 
 def _arrivals(state: EngineState, params: EngineParams,
@@ -1190,88 +1268,106 @@ def _arrivals(state: EngineState, params: EngineParams,
 def _step_impl(state: EngineState, params: EngineParams,
                axis_name: Optional[str] = None
                ) -> Tuple[EngineState, PeriodMetrics]:
-    """One pure period: arrivals + `_period_impl` + state/metric assembly."""
-    t = state.period
-    D = state.pending.shape[0]
-    H = params.drift.shape[1]
-    drift_t = jnp.take(params.drift, t % H, axis=1)
-    outage_t = jnp.take(params.outage, t % H, axis=1)
-    # A basis optimal for last period's LP is meaningless when the ES
-    # column set changed underneath it (outage flipping on/off swaps the
-    # offload columns for the disabled sentinel): mask those lanes back to
-    # -1 so `_warm_init` cold-starts them instead of factoring a basis of
-    # the wrong problem.
-    outage_prev = jnp.take(params.outage, (t - 1) % H, axis=1)
-    stale = (t > 0) & (outage_prev != outage_t)
+    """One pure period: arrivals + `_period_impl` + state/metric assembly.
+
+    Every op of the period sits under one top-level `jax.named_scope`,
+    in order: ``arrivals``, ``route`` (mobility armed), ``plan`` (or
+    ``hi_gate``), ``admission``, ``replan``, ``ladder`` (chaos armed),
+    ``pricing``; ``plan`` and ``replan`` nest ``lp`` and ``round``.
+    Scopes are HLO metadata only: they change no op and no number."""
+    with jax.named_scope("arrivals"):
+        t = state.period
+        D = state.pending.shape[0]
+        H = params.drift.shape[1]
+        drift_t = jnp.take(params.drift, t % H, axis=1)
+        outage_t = jnp.take(params.outage, t % H, axis=1)
+        # A basis optimal for last period's LP is meaningless when the ES
+        # column set changed underneath it (outage flipping on/off swaps
+        # the offload columns for the disabled sentinel): mask those lanes
+        # back to -1 so `_warm_init` cold-starts them instead of factoring
+        # a basis of the wrong problem.
+        outage_prev = jnp.take(params.outage, (t - 1) % H, axis=1)
+        stale = (t > 0) & (outage_prev != outage_t)
     # ---- mobility: move, route, detect handover -------------------------
+    n_handover = None
     if params.mobility_mode != "off":
-        mob = params.mobility
-        if params.mobility_mode == "replay":
-            pos_t = jnp.take(mob.trace, t % mob.trace.shape[0], axis=0)
-        else:                                               # random walk
-            # folded replayed stream (the fault_seed idiom): per-device
-            # GLOBAL-id folds, so sharded and unsharded walks agree and
-            # arming mobility never perturbs the arrival PRNG
-            kw = jax.random.fold_in(
-                jax.random.PRNGKey(params.mobility_seed), t)
-            offset = (jax.lax.axis_index(axis_name) * D
-                      if axis_name else jnp.int32(0))
-            gid = offset + jnp.arange(D, dtype=jnp.int32)
-            kd = jax.vmap(lambda g: jax.random.fold_in(kw, g))(gid)
-            steps = jax.vmap(
-                lambda k: jax.random.normal(k, (2,), jnp.float64))(kd)
-            pos_t = state.pos + mob.walk_sigma * steps
-        load_frac = state.cell_load / (params.servers_per_cell * params.T)
-        cell_t, covered, link_factor = route_cells(
-            pos_t, mob, load_frac, params.routing)
-        # handover: the previous cell's basis labels an LP whose ES
-        # column was priced for a different link — cold-start it, and
-        # migrate the ES belief back to the new cell's nominal table
-        switched = (t > 0) & (cell_t != state.cell)
-        stale = stale | switched
-        es_belief0 = jnp.where(switched[:, None], params.p_es,
-                               state.p_es_belief)
-        n_handover = jnp.sum(switched.astype(jnp.int32))
+        with jax.named_scope("route"):
+            mob = params.mobility
+            if params.mobility_mode == "replay":
+                pos_t = jnp.take(mob.trace, t % mob.trace.shape[0], axis=0)
+            else:                                           # random walk
+                # folded replayed stream (the fault_seed idiom): per-device
+                # GLOBAL-id folds, so sharded and unsharded walks agree and
+                # arming mobility never perturbs the arrival PRNG
+                kw = jax.random.fold_in(
+                    jax.random.PRNGKey(params.mobility_seed), t)
+                offset = (jax.lax.axis_index(axis_name) * D
+                          if axis_name else jnp.int32(0))
+                gid = offset + jnp.arange(D, dtype=jnp.int32)
+                kd = jax.vmap(lambda g: jax.random.fold_in(kw, g))(gid)
+                steps = jax.vmap(
+                    lambda k: jax.random.normal(k, (2,), jnp.float64))(kd)
+                pos_t = state.pos + mob.walk_sigma * steps
+            load_frac = state.cell_load / (params.servers_per_cell
+                                           * params.T)
+            cell_t, covered, link_factor = route_cells(
+                pos_t, mob, load_frac, params.routing)
+            # handover: the previous cell's basis labels an LP whose ES
+            # column was priced for a different link — cold-start it, and
+            # migrate the ES belief back to the new cell's nominal table
+            switched = (t > 0) & (cell_t != state.cell)
+            stale = stale | switched
+            es_belief0 = jnp.where(switched[:, None], params.p_es,
+                                   state.p_es_belief)
+            n_handover = jnp.sum(switched.astype(jnp.int32))
     else:
         pos_t, cell_t = state.pos, state.cell
         covered = link_factor = None
         es_belief0 = state.p_es_belief
-        n_handover = jnp.zeros((), jnp.int32)
-    warm0 = jnp.where(stale[:, None], jnp.int32(-1), state.warm_basis)
-    ci, take, pending, head, key = _arrivals(state, params, axis_name)
+    with jax.named_scope("arrivals"):
+        warm0 = jnp.where(stale[:, None], jnp.int32(-1), state.warm_basis)
+        ci, take, pending, head, key = _arrivals(state, params, axis_name)
     # the fault stream is replayed — folded from a dedicated seed, never
     # drawn from state.key — so arming chaos leaves the arrival (and
     # fault-free metric) trajectory bitwise-untouched, and the host
     # delegation can reproduce the exact same draw per period
-    fkey = (jax.random.fold_in(jax.random.PRNGKey(params.fault_seed), t)
-            if params.chaos else None)
+    fkey = hikey = None
+    if params.chaos:
+        with jax.named_scope("ladder"):
+            fkey = jax.random.fold_in(
+                jax.random.PRNGKey(params.fault_seed), t)
     # the confidence stream is replayed the same way — folded from its
     # own seed — so arming HI never perturbs arrivals either
-    hikey = (jax.random.fold_in(jax.random.PRNGKey(params.hi_seed), t)
-             if params.hi_armed else None)
+    if params.hi_armed:
+        with jax.named_scope("hi_gate"):
+            hikey = jax.random.fold_in(jax.random.PRNGKey(params.hi_seed), t)
     (new_belief, new_warm, upd, _factor, new_es_belief, cell_load,
      new_hi, m) = _period_impl(
         state.p_ed, warm0, ci, take, drift_t, outage_t, params,
         axis_name=axis_name, fault_key=fkey, es_belief=es_belief0,
         link_factor=link_factor, covered=covered, cell=cell_t,
         hi_key=hikey, hi_state=state.hi, hi_t=t)
-    backlog = jnp.sum(pending)
-    if axis_name:
-        backlog = jax.lax.psum(backlog, axis_name)
-        n_handover = jax.lax.psum(n_handover, axis_name)
-    n_jobs = m["n_jobs"]
-    metrics = PeriodMetrics(
-        period=t,
-        mean_job_accuracy=jnp.where(
-            n_jobs > 0, m["total_accuracy"] / jnp.maximum(n_jobs, 1), 0.0),
-        backlog=backlog.astype(jnp.int32),
-        n_handover=n_handover.astype(jnp.int32), **m)
-    new_state = EngineState(
-        period=(t + 1).astype(jnp.int32), key=key, p_ed=new_belief,
-        pending=pending, head=head, warm_basis=new_warm,
-        n_updates=(state.n_updates + upd.astype(jnp.int32)),
-        pos=pos_t, cell=cell_t.astype(jnp.int32), cell_load=cell_load,
-        p_es_belief=new_es_belief, hi=new_hi)
+    with jax.named_scope("pricing"):
+        backlog = jnp.sum(pending)
+        if n_handover is None:
+            n_handover = jnp.zeros((), jnp.int32)
+        if axis_name:
+            backlog = jax.lax.psum(backlog, axis_name)
+            n_handover = jax.lax.psum(n_handover, axis_name)
+        n_jobs = m["n_jobs"]
+        metrics = PeriodMetrics(
+            period=t,
+            mean_job_accuracy=jnp.where(
+                n_jobs > 0, m["total_accuracy"] / jnp.maximum(n_jobs, 1),
+                0.0),
+            backlog=backlog.astype(jnp.int32),
+            n_handover=n_handover.astype(jnp.int32), **m)
+        new_state = EngineState(
+            period=(t + 1).astype(jnp.int32), key=key, p_ed=new_belief,
+            pending=pending, head=head, warm_basis=new_warm,
+            n_updates=(state.n_updates + upd.astype(jnp.int32)),
+            pos=pos_t, cell=cell_t.astype(jnp.int32), cell_load=cell_load,
+            p_es_belief=new_es_belief, hi=new_hi)
     return new_state, metrics
 
 
@@ -1351,13 +1447,30 @@ def _check_horizon(state: EngineState, params: EngineParams,
             f"arrivals='poisson'")
 
 
+@contextlib.contextmanager
+def _entry_spans(entry: str, state: EngineState, params: EngineParams,
+                 periods: int):
+    """Host spans of a public entry point, for `jax.profiler` traces: an
+    outer ``repro.<entry>`` holding, in order, ``repro.validate`` (the
+    float64 checks), ``repro.horizon`` (`_check_horizon`, whose
+    ``state.period`` read is a device-to-host copy) and ``repro.launch``
+    (`x64_scope` and the jitted call until it returns, the body of the
+    ``with``).  With no profiler running each span costs about a
+    microsecond."""
+    with jax.profiler.TraceAnnotation(f"repro.{entry}"):
+        with jax.profiler.TraceAnnotation("repro.validate"):
+            _require_f64("state", state)
+            _require_f64("params", params)
+        with jax.profiler.TraceAnnotation("repro.horizon"):
+            _check_horizon(state, params, periods)
+        with jax.profiler.TraceAnnotation("repro.launch"), x64_scope():
+            yield
+
+
 def step(state: EngineState, params: EngineParams
          ) -> Tuple[EngineState, PeriodMetrics]:
     """One jitted period transition (float64, like the host LP path)."""
-    _require_f64("state", state)
-    _require_f64("params", params)
-    _check_horizon(state, params, 1)
-    with x64_scope():
+    with _entry_spans("step", state, params, 1):
         return _step_jit(state, params)
 
 
@@ -1372,11 +1485,8 @@ def rollout(state: EngineState, params: EngineParams, periods: int,
     caller must not reuse ``state`` afterwards) — at the 100k-device
     scale this halves peak memory, since the old and new fleet state
     never need to coexist."""
-    _require_f64("state", state)
-    _require_f64("params", params)
-    _check_horizon(state, params, periods)
     fn = _rollout_donate if donate else _rollout_jit
-    with x64_scope():
+    with _entry_spans("rollout", state, params, periods):
         return fn(state, params, int(periods))
 
 
@@ -1642,10 +1752,7 @@ def step_sharded(state: EngineState, params: EngineParams, mesh
     psum-reduced, so the output matches the unsharded `step`."""
     _reject_diff_sharded(params)
     _reject_hi_sharded(params)
-    _require_f64("state", state)
-    _require_f64("params", params)
-    _check_horizon(state, params, 1)
-    with x64_scope():
+    with _entry_spans("step_sharded", state, params, 1):
         return _sharded_fn(mesh, None, _aux_of(params))(state, params)
 
 
@@ -1657,9 +1764,6 @@ def rollout_sharded(state: EngineState, params: EngineParams,
     consumes the input state's shards (see `rollout`)."""
     _reject_diff_sharded(params)
     _reject_hi_sharded(params)
-    _require_f64("state", state)
-    _require_f64("params", params)
-    _check_horizon(state, params, periods)
-    with x64_scope():
+    with _entry_spans("rollout_sharded", state, params, periods):
         return _sharded_fn(mesh, int(periods), _aux_of(params),
                            donate)(state, params)
