@@ -73,8 +73,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.amr2 import (build_lp_arrays_jnp, round_relaxation_jnp,
-                         soft_assignment_weights, straight_through_weights)
+from ..core.amr2 import (build_lp_arrays_jnp, lp_column_rows,
+                         round_relaxation_jnp, soft_assignment_weights,
+                         straight_through_weights)
 from ..core.dual import _dual_one
 from ..core.faults import (FaultModel, greedy_local_fill,
                            realize_execution, sample_realization)
@@ -776,12 +777,17 @@ def _plan_flat(params: EngineParams, fp: FleetProblem, warm_basis,
                                                fp.T)
             maxiter = params.maxiter if params.maxiter is not None else \
                 _bucket_maxiter(50 * (A.shape[1] + 2))
-            solve = simplex_batch_grad if params.differentiable \
-                else simplex_batch_core
+            kw = dict(nv=n * (m + 1), maxiter=maxiter, tol=params.tol,
+                      lane_mask=lane_mask, method=params.lp_method)
+            if params.differentiable:
+                # the implicit VJP needs A itself: dense pricing
+                solve = simplex_batch_grad
+            else:
+                solve = simplex_batch_core
+                if params.lp_method == "revised":
+                    kw["col_rows"] = lp_column_rows(n, m)
             x, _fun, st, niter, basis, _ok = solve(
-                A, b, c_full, warm_basis, nv=n * (m + 1), maxiter=maxiter,
-                tol=params.tol, lane_mask=lane_mask,
-                method=params.lp_method)
+                A, b, c_full, warm_basis, **kw)
             xbar = x.reshape(D, n, m + 1)
         with jax.named_scope("round"):
             assign, sched_status, _nf = round_relaxation_jnp(

@@ -412,6 +412,22 @@ def build_lp_arrays_jnp(p_ed, p_es, acc, T):
     return A, b, c_full
 
 
+def lp_column_rows(n: int, m: int) -> np.ndarray:
+    """The rows of the (at most two) nonzeros of each column that
+    `build_lp_arrays_jnp` builds for ``(n, m)``: a (2, C0) int32 array,
+    static at trace time (`lp.simplex_batch_core`'s ``col_rows``).
+
+    Variable ``(job j, model k)`` has its latency in the ED budget row 0
+    (k < m) or the ES budget row 1 (k = m), and a 1 in assignment row
+    ``2 + j``; slack ``s`` (s = 0, 1) has its 1 in budget row ``s`` and a
+    0 in the other budget row."""
+    mp1 = m + 1
+    k = np.tile(np.arange(mp1), n)
+    ra = np.concatenate([(k == m).astype(np.int32), [0, 1]])
+    rb = np.concatenate([2 + np.repeat(np.arange(n), mp1), [1, 0]])
+    return np.stack([ra, rb]).astype(np.int32)
+
+
 def round_relaxation_jnp(p_ed, p_es, acc, T, xbar, status, *,
                          frac_tol: float = _FRAC_TOL):
     """Traceable `round_relaxation_batch`: Algorithm 1's rounding as pure

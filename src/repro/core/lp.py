@@ -47,7 +47,12 @@ that factor plus the basic solution, prices entering columns on demand
 from the ORIGINAL column data (one BTRAN + a (R, C0) product per
 iteration), and maintains the factor across pivots with product-form (eta)
 rank-1 updates — `_batched_inverse` runs once per warm start, never per
-pivot, and the C0-wide tableau is never materialized.  Selection rules,
+pivot, and the C0-wide tableau is never materialized.  A caller that knows
+its LP's columns hold at most two nonzeros (the engine, for AMR²) passes
+their static rows (``col_rows``): pricing and FTRAN then read the two
+values per column, and no pivot contracts against the (R, C0) data —
+on a TPU, which emulates float64, those contractions dominated the
+pivot.  Selection rules,
 warm/cold/rejection semantics, statuses and pivot counts match the tableau
 path (the parity tests pin status/basis/niter exactly and x/fun to solver
 tolerance); summation orders differ, so results are not bit-identical.
@@ -568,12 +573,19 @@ def _two_phase_virtual(tabA, rhs, bas, b, c_full, *, nv, maxiter, tol,
 # Reduced-tableau revised simplex (batched)
 # --------------------------------------------------------------------------
 def _reduced_pivot_batch(A, c_phase, Binv, xB, bas, use_bland, may_pivot,
-                         lane_ok, art_cost, tol, impl: str):
+                         lane_ok, art_cost, tol, impl: str, cols=None):
     """One fused revised-simplex iteration across the whole lane stack.
 
     ``impl="jnp"`` uses the shared reference op; ``impl="pallas"`` routes
     through the fused `kernels/simplex_pivot.reduced_pivot` TPU kernel
-    (interpret mode off-TPU, like the dense pivot)."""
+    (interpret mode off-TPU, like the dense pivot).  ``cols`` (the column
+    form of `_column_form`) prices and runs FTRAN from it instead of
+    contracting against ``A`` (jnp only)."""
+    if cols is not None:
+        from ..kernels.simplex_pivot.ref import reduced_pivot_columns_ref
+        return reduced_pivot_columns_ref(cols, c_phase, Binv, xB, bas,
+                                         use_bland, may_pivot, lane_ok,
+                                         art_cost=art_cost, tol=tol)
     if impl == "pallas":
         from ..kernels.simplex_pivot import ops as _pivot_ops
         return _pivot_ops.reduced_pivot(A, c_phase, Binv, xB, bas,
@@ -587,7 +599,7 @@ def _reduced_pivot_batch(A, c_phase, Binv, xB, bas, use_bland, may_pivot,
 
 def _revised_phase(A, c_phase, Binv, xB, bas, *, art_cost: float,
                    maxiter: int, tol: float, bland_after: int, impl: str,
-                   lane_ok, it0=None):
+                   lane_ok, it0=None, cols=None):
     """Masked batched simplex phase in REDUCED form: only the (R, R)
     basis-inverse factor and the basic solution are carried per lane;
     every iteration prices all C0 columns on demand out of the factor and
@@ -599,8 +611,10 @@ def _revised_phase(A, c_phase, Binv, xB, bas, *, art_cost: float,
     status/iteration bookkeeping match `_phase_batched`; ``art_cost`` is
     the phase cost of virtual artificial labels (1 in phase 1, 0 in
     phase 2) and ``it0`` seeds the per-lane counters (shared two-phase
-    maxiter budget)."""
-    from ..kernels.simplex_pivot.ref import price_reduced_ref
+    maxiter budget).  ``cols`` (see `_column_form`) selects the column
+    pricer for the pivots and the final optimality check."""
+    from ..kernels.simplex_pivot.ref import (price_columns_ref,
+                                            price_reduced_ref)
     B = A.shape[0]
     lane_ok = (jnp.ones(B, dtype=bool) if lane_ok is None
                else jnp.asarray(lane_ok, dtype=bool))
@@ -616,7 +630,7 @@ def _revised_phase(A, c_phase, Binv, xB, bas, *, art_cost: float,
             _reduced_pivot_batch(A, c_phase, Binv, xB, bas,
                                  degen >= bland_after,
                                  running & (it < maxiter), lane_ok,
-                                 art_cost, tol, impl)
+                                 art_cost, tol, impl, cols)
         status = jnp.where(running & ~has_enter, OPTIMAL, status)
         active = running & has_enter & (it < maxiter)
         status = jnp.where(active & unbounded, UNBOUNDED, status)
@@ -631,14 +645,16 @@ def _revised_phase(A, c_phase, Binv, xB, bas, *, art_cost: float,
             jnp.zeros(B, jnp.int32) if it0 is None else it0,
             jnp.full(B, ITERATION_LIMIT, jnp.int32), jnp.zeros(B, jnp.int32))
     Binv, xB, bas, it, status, _ = jax.lax.while_loop(cond, body, init)
-    rc = price_reduced_ref(A, c_phase, Binv, bas, art_cost)
+    rc = (price_reduced_ref(A, c_phase, Binv, bas, art_cost)
+          if cols is None else
+          price_columns_ref(cols, c_phase, Binv, bas, art_cost))
     done = ~((rc < -tol) & lane_ok[:, None]).any(axis=1)
     status = jnp.where((status == ITERATION_LIMIT) & done, OPTIMAL, status)
     return Binv, xB, bas, it, status
 
 
 def _revised_two_phase(A, b, c_full, Binv, xB, bas, *, nv, maxiter, tol,
-                       bland_after, impl, lane_mask=None):
+                       bland_after, impl, lane_mask=None, cols=None):
     """Both simplex phases in reduced form (`_two_phase_virtual`'s twin).
 
     Phase 1 minimizes the sum of basic virtual artificials (real columns
@@ -653,7 +669,7 @@ def _revised_two_phase(A, b, c_full, Binv, xB, bas, *, nv, maxiter, tol,
     Binv, xB, bas, it1, status1 = _revised_phase(
         A, jnp.zeros_like(c_full), Binv, xB, bas, art_cost=1.0,
         maxiter=maxiter, tol=tol, bland_after=bland_after, impl=impl,
-        lane_ok=lane_mask)
+        lane_ok=lane_mask, cols=cols)
     art_sum = jnp.sum(jnp.where(bas >= C0, xB, 0.0), axis=1)
     infeasible = art_sum > max(tol, 1e-5) * (1.0 + jnp.abs(b).sum(axis=1))
     if lane_mask is not None:
@@ -662,7 +678,8 @@ def _revised_two_phase(A, b, c_full, Binv, xB, bas, *, nv, maxiter, tol,
     # phase 2 resumes phase 1's per-lane counts: one shared maxiter budget
     Binv, xB, bas, it2, status2 = _revised_phase(
         A, c_full, Binv, xB, bas, art_cost=0.0, maxiter=maxiter, tol=tol,
-        bland_after=bland_after, impl=impl, lane_ok=lane_mask, it0=it1)
+        bland_after=bland_after, impl=impl, lane_ok=lane_mask, it0=it1,
+        cols=cols)
 
     vals = jnp.where(bas < C0, xB, 0.0)
     x = jnp.zeros((B, C0), dtype)
@@ -678,14 +695,32 @@ def _revised_two_phase(A, b, c_full, Binv, xB, bas, *, nv, maxiter, tol,
     return x[:, :nv], fun, status, it2, bas
 
 
+def _column_form(A, col_rows):
+    """The column form of an LP whose every column has at most two
+    nonzeros: ``(ra, rb, va, vb)`` with the static row pair ``col_rows``
+    (2, C0) — for a column with one nonzero, ``rb`` names any row where
+    it is 0 — and the values gathered once from ``A`` (B, R, C0), so
+    there is no second copy of the coefficients to drift from it."""
+    B, R, C0 = A.shape
+    rows = np.asarray(col_rows)
+    if rows.shape != (2, C0) or rows.min() < 0 or rows.max() >= R:
+        raise ValueError(f"col_rows must be (2, {C0}) row indices in "
+                         f"[0, {R}); got shape {rows.shape}")
+    ra, rb = rows.astype(np.int32)
+    cidx = np.arange(C0)
+    return ra, rb, A[:, ra, cidx], A[:, rb, cidx]
+
+
 def _revised_core(A, b, c_full, basis0, *, nv, maxiter, tol,
-                  bland_after=BLAND_AFTER, impl="jnp", lane_mask=None):
+                  bland_after=BLAND_AFTER, impl="jnp", lane_mask=None,
+                  col_rows=None):
     """Traceable warm-OR-cold batched revised simplex — the
     ``method="revised"`` body of `simplex_batch_core`, with the same
     start/rejection semantics: a cold lane's factor is the identity
     (xB = b, every row basic on its virtual artificial) and a warm lane
     reuses its repaired `_warm_init_reduced` factor; rejected lanes start
-    cold in the same call.  Returns the `simplex_batch_core` tuple."""
+    cold in the same call.  ``col_rows`` prices from the column form
+    (`_column_form`).  Returns the `simplex_batch_core` tuple."""
     B, R, C0 = A.shape
     dtype = A.dtype
     rows = jnp.arange(R, dtype=jnp.int32)
@@ -701,9 +736,10 @@ def _revised_core(A, b, c_full, basis0, *, nv, maxiter, tol,
         xB = jnp.where(warm_ok[:, None], rhs_w, b)
         bas = jnp.where(warm_ok[:, None], bas_w, bas_c)
 
+    cols = None if col_rows is None else _column_form(A, col_rows)
     x, fun, status, niter, bases = _revised_two_phase(
         A, b, c_full, Binv, xB, bas, nv=nv, maxiter=maxiter, tol=tol,
-        bland_after=bland_after, impl=impl, lane_mask=lane_mask)
+        bland_after=bland_after, impl=impl, lane_mask=lane_mask, cols=cols)
     return x, fun, status, niter, bases, warm_ok
 
 
@@ -742,7 +778,7 @@ def _warm_batch_jit(A_j, b_j, c_j, basis0, *, nv, maxiter, tol,
 def simplex_batch_core(A, b, c_full, basis0, *, nv: int, maxiter: int,
                        tol: float = 1e-7, bland_after: int = BLAND_AFTER,
                        impl: str = "jnp", lane_mask=None,
-                       method: str = "tableau"):
+                       method: str = "tableau", col_rows=None):
     """Traceable warm-OR-cold batched two-phase simplex (the scan path).
 
     Unlike `solve_lp_batch` — which accepts warm lanes via `_warm_batch_jit`
@@ -781,13 +817,26 @@ def simplex_batch_core(A, b, c_full, basis0, *, nv: int, maxiter: int,
     and to solver tolerance on x/fun (pinned by the parity tests), but not
     bit-for-bit — their floating-point summation orders differ.
 
+    ``col_rows`` (revised, ``impl="jnp"`` only): for an LP whose every
+    column has at most two nonzeros, a static (2, C0) int array naming
+    each column's two rows (for a one-nonzero column, any row where it
+    is 0; `amr2.lp_column_rows` for the AMR² relaxation).  The revised
+    pivots then price and run FTRAN from the two gathered values per
+    column (`kernels.simplex_pivot.ref.reduced_pivot_columns_ref`): no
+    float64 contraction against ``A`` per pivot, the same terms a dense
+    one would add save exact zeros, and the same selection and update.
+    ``None`` (default) contracts against ``A``.
+
     Expects canonicalised inputs (``b >= 0``; see `_canonicalize_batch`).
     Returns ``(x (B, nv), fun, status, niter, basis, warm_ok)``.
     """
+    if col_rows is not None and (method, impl) != ("revised", "jnp"):
+        raise ValueError("col_rows needs method='revised' and impl='jnp' "
+                         f"(got method={method!r}, impl={impl!r})")
     if method == "revised":
         return _revised_core(A, b, c_full, basis0, nv=nv, maxiter=maxiter,
                              tol=tol, bland_after=bland_after, impl=impl,
-                             lane_mask=lane_mask)
+                             lane_mask=lane_mask, col_rows=col_rows)
     if method != "tableau":
         raise ValueError(f"unknown simplex method {method!r}; expected "
                          f"'tableau' or 'revised'")

@@ -13,6 +13,11 @@ tests:
     (R, R) basis inverse and the basic solution are updated; entering
     columns are priced on demand from the original (R, C0) column data, so
     the C0-wide tableau is never materialized.
+    `reduced_pivot_columns_ref` is its twin for an LP whose columns hold
+    at most two nonzeros (the AMR² relaxation): it prices and runs FTRAN
+    from that column form instead of contracting against ``A``, and
+    shares the selection and eta update (`select_update_ref`).  It has no
+    Pallas kernel.
   * `basis_columns_ref` / `kkt_vjp_ref` — the per-lane basis gather and
     the KKT adjoint solve behind the implicit-gradient simplex
     (`core.lp` ``differentiable=True``): at a converged basis the optimum
@@ -49,6 +54,16 @@ def pivot_update_ref(tabs: jnp.ndarray, r: jnp.ndarray, j: jnp.ndarray,
     return jnp.where(mask[:, None, None], new, tabs)
 
 
+def _multipliers_ref(c_phase, Binv, basis, art_cost):
+    """BTRAN: the simplex multipliers ``y = c_B Binv`` (B, R), with virtual
+    artificial labels (>= C0) priced at ``art_cost``."""
+    C0 = c_phase.shape[1]
+    cB = jnp.where(
+        basis >= C0, jnp.asarray(art_cost, c_phase.dtype),
+        jnp.take_along_axis(c_phase, jnp.clip(basis, 0, C0 - 1), axis=1))
+    return jnp.einsum("br,brk->bk", cB, Binv)
+
+
 def price_reduced_ref(A, c_phase, Binv, basis, art_cost):
     """Reduced costs out of the basis-inverse factor (one BTRAN + pricing).
 
@@ -56,41 +71,49 @@ def price_reduced_ref(A, c_phase, Binv, basis, art_cost):
     (B, R, R); basis: (B, R) labels — entries >= C0 are VIRTUAL artificials
     (no column exists; they price at ``art_cost``: 1 in phase 1, 0 in
     phase 2).  Returns rc (B, C0)."""
-    C0 = A.shape[2]
-    cB = jnp.where(
-        basis >= C0, jnp.asarray(art_cost, A.dtype),
-        jnp.take_along_axis(c_phase, jnp.clip(basis, 0, C0 - 1), axis=1))
-    y = jnp.einsum("br,brk->bk", cB, Binv)          # simplex multipliers
+    y = _multipliers_ref(c_phase, Binv, basis, art_cost)
     return c_phase - jnp.einsum("bk,bkc->bc", y, A)
 
 
-def reduced_pivot_ref(A, c_phase, Binv, xB, basis, use_bland, may_pivot,
-                      lane_ok, *, art_cost: float, tol: float):
-    """One fused revised-simplex iteration across the whole lane stack.
+def price_columns_ref(cols, c_phase, Binv, basis, art_cost):
+    """`price_reduced_ref` for an LP whose every column has at most two
+    nonzeros, given in column form ``cols = (ra, rb, va, vb)``: column
+    ``c`` holds ``va[:, c]`` in row ``ra[c]`` and ``vb[:, c]`` in row
+    ``rb[c]`` and zeros elsewhere (``ra``, ``rb``: static (C0,) int
+    arrays; ``va``, ``vb``: (B, C0)).  ``y·A`` is then two gathers of the
+    multipliers, two products and one add per column — the terms a dense
+    contraction would add for the other rows are exact zeros."""
+    ra, rb, va, vb = cols
+    y = _multipliers_ref(c_phase, Binv, basis, art_cost)
+    return c_phase - (y[:, ra] * va + y[:, rb] * vb)
 
-    Prices every column out of the current factor (`price_reduced_ref`),
-    picks the entering column (Dantzig, or Bland's smallest index where
-    ``use_bland``), runs the ratio test on the FTRAN-transformed entering
-    column (with `core.lp`'s artificial drive-out rule and
-    smallest-basis-index tie-break), and applies the product-form (eta)
-    rank-1 update to ``[Binv | xB]`` — the revised-simplex replacement for
-    the dense (R+1, C0+1) tableau pivot of `pivot_update_ref`.
 
-    A: (B, R, C0); c_phase: (B, C0); Binv: (B, R, R); xB: (B, R) basic
-    solution; basis: (B, R) labels (>= C0 virtual artificial);
-    use_bland / may_pivot / lane_ok: (B,) bool — ``lane_ok`` False lanes
-    never produce an entering column (the masked-lane contract), and the
-    update is applied only where ``may_pivot & has_enter & ~unbounded``.
+def ftran_columns_ref(cols, Binv, j):
+    """FTRAN ``Binv A_j`` (B, R) of each lane's entering column ``j`` (B,)
+    from the column form of `price_columns_ref`: one per-lane gather of
+    the two ``Binv`` columns the entering column's rows select, and one
+    axpy."""
+    ra, rb, va, vb = cols
+    rows = jnp.stack([jnp.asarray(ra)[j], jnp.asarray(rb)[j]], axis=1)
+    g = jnp.take_along_axis(Binv, rows[:, None, :], axis=2)     # (B, R, 2)
+    vaj = jnp.take_along_axis(va, j[:, None], axis=1)           # (B, 1)
+    vbj = jnp.take_along_axis(vb, j[:, None], axis=1)
+    return g[..., 0] * vaj + g[..., 1] * vbj
 
-    Returns ``(Binv', xB', basis', has_enter, unbounded, degenerate)``
-    with the three flags (B,) bool (``degenerate``: min ratio <= tol,
-    meaningful only on lanes that pivoted).
-    """
-    B, R, C0 = A.shape
-    dtype = A.dtype
+
+def select_update_ref(rc, ftran, Binv, xB, basis, use_bland, may_pivot,
+                      lane_ok, *, tol: float):
+    """Entering/leaving selection and the eta update of one revised-simplex
+    iteration, given the reduced costs ``rc`` (B, C0) and ``ftran``, a
+    function of the entering columns ``j`` (B,) that returns ``Binv A_j``
+    (B, R).  The one body behind both pricers (`reduced_pivot_ref`,
+    `reduced_pivot_columns_ref`); the arguments and the return value are
+    theirs."""
+    C0 = rc.shape[1]
+    R = Binv.shape[1]
+    dtype = Binv.dtype
     intmax = jnp.iinfo(jnp.int32).max
 
-    rc = price_reduced_ref(A, c_phase, Binv, basis, art_cost)
     enter = (rc < -tol) & lane_ok[:, None]
     has_enter = enter.any(axis=1)
     score = jnp.where(enter, rc, jnp.inf)
@@ -99,9 +122,7 @@ def reduced_pivot_ref(A, c_phase, Binv, xB, basis, use_bland, may_pivot,
     j = jnp.where(use_bland, j_bland, j_dantzig).astype(jnp.int32)
     j = jnp.where(has_enter, j, 0)                  # safe gather index
 
-    # FTRAN: entering column in basis coordinates
-    Aj = jnp.take_along_axis(A, j[:, None, None], axis=2)[..., 0]  # (B, R)
-    d = jnp.einsum("brk,bk->br", Binv, Aj)
+    d = ftran(j)            # entering column in basis coordinates
     pos = d > tol
     ratio = jnp.where(pos, xB / jnp.where(pos, d, 1.0), jnp.inf)
     art_basic = (basis >= C0) & (jnp.abs(d) > tol) & (xB <= tol)
@@ -126,6 +147,49 @@ def reduced_pivot_ref(A, c_phase, Binv, xB, basis, use_bland, may_pivot,
     basis = jnp.where(do[:, None] & is_r, j[:, None], basis)
     return (F[:, :, :R], F[:, :, R], basis.astype(jnp.int32),
             has_enter, unbounded, rmin <= tol)
+
+
+def reduced_pivot_ref(A, c_phase, Binv, xB, basis, use_bland, may_pivot,
+                      lane_ok, *, art_cost: float, tol: float):
+    """One fused revised-simplex iteration across the whole lane stack.
+
+    Prices every column out of the current factor (`price_reduced_ref`),
+    picks the entering column (Dantzig, or Bland's smallest index where
+    ``use_bland``), runs the ratio test on the FTRAN-transformed entering
+    column (with `core.lp`'s artificial drive-out rule and
+    smallest-basis-index tie-break), and applies the product-form (eta)
+    rank-1 update to ``[Binv | xB]`` — the revised-simplex replacement for
+    the dense (R+1, C0+1) tableau pivot of `pivot_update_ref`.
+
+    A: (B, R, C0); c_phase: (B, C0); Binv: (B, R, R); xB: (B, R) basic
+    solution; basis: (B, R) labels (>= C0 virtual artificial);
+    use_bland / may_pivot / lane_ok: (B,) bool — ``lane_ok`` False lanes
+    never produce an entering column (the masked-lane contract), and the
+    update is applied only where ``may_pivot & has_enter & ~unbounded``.
+
+    Returns ``(Binv', xB', basis', has_enter, unbounded, degenerate)``
+    with the three flags (B,) bool (``degenerate``: min ratio <= tol,
+    meaningful only on lanes that pivoted).
+    """
+    def ftran(j):
+        Aj = jnp.take_along_axis(A, j[:, None, None], axis=2)[..., 0]
+        return jnp.einsum("brk,bk->br", Binv, Aj)
+
+    rc = price_reduced_ref(A, c_phase, Binv, basis, art_cost)
+    return select_update_ref(rc, ftran, Binv, xB, basis, use_bland,
+                             may_pivot, lane_ok, tol=tol)
+
+
+def reduced_pivot_columns_ref(cols, c_phase, Binv, xB, basis, use_bland,
+                              may_pivot, lane_ok, *, art_cost: float,
+                              tol: float):
+    """`reduced_pivot_ref` priced and FTRAN-ed from the column form of
+    `price_columns_ref` (``cols`` in place of ``A``): no contraction
+    against the columns, the same selection and update."""
+    rc = price_columns_ref(cols, c_phase, Binv, basis, art_cost)
+    return select_update_ref(
+        rc, lambda j: ftran_columns_ref(cols, Binv, j), Binv, xB, basis,
+        use_bland, may_pivot, lane_ok, tol=tol)
 
 
 def basis_columns_ref(A, basis):

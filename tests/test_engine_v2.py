@@ -409,27 +409,106 @@ def test_engine_pytrees_roundtrip():
 # ---------------------------------------------------------------------------
 # reduced-tableau LP method, buffer donation, dtype guard, plan chunking
 # ---------------------------------------------------------------------------
-def test_rollout_lp_method_revised_matches_tableau():
+def _dense_priced(monkeypatch):
+    """Run the engine's revised simplex with dense pricing (``col_rows``
+    withheld, as before the column pricer) for the rest of the test: a
+    rollout jitted from a new function, so that no cached trace of the
+    column path is reused."""
+    import jax
+    monkeypatch.setattr(E, "lp_column_rows", lambda n, m: None)
+    return jax.jit(lambda s, p, periods: E._rollout_impl(s, p, periods),
+                   static_argnames="periods")
+
+
+@pytest.mark.parametrize("n_devices,bases_match_tableau",
+                         [(8, True), (256, False)])
+def test_rollout_lp_method_revised_matches_tableau(n_devices,
+                                                   bases_match_tableau,
+                                                   monkeypatch):
     """The engine on `lp_method="revised"` must replay the tableau
     engine's trajectory: same integer metrics, same warm-basis carry,
     accuracies to fp noise.  The carried basis is compared as a label
     SET per device: the two representations reach the same optimal
     vertex but may order its rows differently (the leaving-row slot
     depends on the pivot sequence, which differs between the dense
-    tableau and the reduced factor on degenerate ties)."""
-    cfg = _config(8, horizon=10)
+    tableau and the reduced factor on degenerate ties).
+
+    At 256 devices (Poisson arrivals; every period replans and masks
+    job slots) a few devices end on another optimal basis of a
+    degenerate vertex under the two representations — with dense
+    pricing too — so there the label sets are pinned against the
+    revised engine priced densely: the column pricer keeps its basis
+    carry, integer metrics and pivot slots."""
+    from repro.core.types import x64_scope
+    cfg = _config(n_devices, horizon=10)
     pt = E.EngineParams.from_config(cfg, horizon=10)
     pr = E.EngineParams.from_config(cfg, horizon=10, lp_method="revised")
     assert pt.lp_method == "tableau" and pr.lp_method == "revised"
     st, mt = E.rollout(E.init_state(pt), pt, 6)
     sr, mr = E.rollout(E.init_state(pr), pr, 6)
+    assert np.asarray(mr.n_backpressured).all()     # replans every period
     for f in INT_FIELDS:
         np.testing.assert_array_equal(np.asarray(getattr(mr, f)),
                                       np.asarray(getattr(mt, f)), f)
     np.testing.assert_allclose(np.asarray(mr.total_accuracy),
                                np.asarray(mt.total_accuracy), atol=1e-12)
+    with x64_scope():
+        sd, md = _dense_priced(monkeypatch)(E.init_state(pr), pr,
+                                            periods=6)
+    for f in INT_FIELDS + ("lp_pivot_slots",):
+        np.testing.assert_array_equal(np.asarray(getattr(mr, f)),
+                                      np.asarray(getattr(md, f)), f)
+    np.testing.assert_allclose(np.asarray(mr.total_accuracy),
+                               np.asarray(md.total_accuracy), atol=1e-12)
     np.testing.assert_array_equal(np.sort(np.asarray(sr.warm_basis), -1),
-                                  np.sort(np.asarray(st.warm_basis), -1))
+                                  np.sort(np.asarray(sd.warm_basis), -1))
+    if bases_match_tableau:
+        np.testing.assert_array_equal(
+            np.sort(np.asarray(sr.warm_basis), -1),
+            np.sort(np.asarray(st.warm_basis), -1))
+
+
+def test_revised_pivot_loops_hold_one_dot(monkeypatch):
+    """On the engine's revised path every pivot loop (phase 1 and 2 of the
+    plan and of the replan) holds one float64 `dot_general`, BTRAN's
+    ``cB·Binv``, and none against A; priced densely, three."""
+    import jax
+    from repro.core.types import x64_scope
+    cfg = _config(8, horizon=10)
+    params = E.EngineParams.from_config(cfg, horizon=10, lp_method="revised")
+    state = E.init_state(params)
+    D, R = params.n_devices, params.n_basis_rows
+
+    def loop_dots():
+        with x64_scope():
+            # a new function each time: no cached trace is reused
+            jaxpr = jax.make_jaxpr(
+                lambda s, p: E._step_impl(s, p))(state, params).jaxpr
+        return [[(e2.invars[0].aval.shape, e2.invars[1].aval.shape,
+                  e2.invars[0].aval.dtype)
+                 for e2 in _eqns(e.params["body_jaxpr"].jaxpr)
+                 if e2.primitive.name == "dot_general"]
+                for e in _eqns(jaxpr) if e.primitive.name == "while"]
+
+    loops = [d for d in loop_dots() if d]
+    assert len(loops) == 4
+    for d in loops:
+        assert d == [((D, R), (D, R, R), np.float64)]
+    monkeypatch.setattr(E, "lp_column_rows", lambda n, m: None)
+    dense = [d for d in loop_dots() if d]
+    assert len(dense) == 4 and all(len(d) == 3 for d in dense)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, those of its sub-jaxprs (loop and
+    branch bodies) included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
 
 
 def test_from_fleet_rejects_unknown_lp_method():

@@ -469,6 +469,152 @@ def test_revised_lane_mask_zeroes_masked_lanes():
     assert (niter[~lane_mask] == 0).all()
 
 
+# ---------------------------------------------------------------------------
+# the column pricer: the AMR² LP's two-nonzero columns (`amr2.lp_column_rows`)
+# ---------------------------------------------------------------------------
+def _amr2_fleet_lps(case, B=48, n=12, m=2):
+    """AMR² LPs as the engine builds them (`build_lp_arrays_jnp`): half the
+    lanes on the paper's latencies (many identical columns, so exact
+    Dantzig ties), half random.  ``case``: "cold"; "masked" (each lane
+    takes a random number of its n job slots, the rest zeroed as the
+    engine pads them); "es_disabled" (the backpressure replan's sentinel
+    ES row, with masked slots too); "warm" (warm bases from the optimal
+    bases of the previous period's perturbed LPs).  Returns ``(A, b, c,
+    basis0, col_rows)``, float64 jnp arrays."""
+    import jax.numpy as jnp
+    from repro.core import InstanceBatch, paper_instance, random_instance
+    from repro.core.amr2 import build_lp_arrays_jnp, lp_column_rows
+    from repro.core.problem import ES_DISABLED_SENTINEL
+    from repro.core.types import x64_scope
+
+    insts = [paper_instance(n, T=1.2, seed=s) if s % 2 else
+             random_instance(n, m, T=1.2, seed=s, p_hi=0.2)
+             for s in range(B)]
+    batch = InstanceBatch.stack(insts)
+    p_ed, p_es = batch.p_ed, batch.p_es
+    rng = np.random.default_rng(17)
+    mask = np.ones((B, n), bool)
+    if case in ("masked", "es_disabled"):
+        mask = np.arange(n)[None, :] < rng.integers(0, n + 1, B)[:, None]
+        p_ed = np.where(mask[..., None], p_ed, 0.0)
+        p_es = np.where(mask, p_es, 0.0)
+    if case == "es_disabled":
+        p_es = np.where(mask, ES_DISABLED_SENTINEL, 0.0)
+    rows = lp_column_rows(n, m)
+    with x64_scope():
+        A, b, c = build_lp_arrays_jnp(jnp.asarray(p_ed), jnp.asarray(p_es),
+                                      jnp.asarray(batch.acc),
+                                      jnp.asarray(batch.T))
+        basis0 = None
+        if case == "warm":
+            A0, b0, c0 = build_lp_arrays_jnp(
+                jnp.asarray(p_ed * rng.uniform(0.5, 2.0, p_ed.shape)),
+                jnp.asarray(p_es * rng.uniform(0.5, 2.0, p_es.shape)),
+                jnp.asarray(batch.acc), jnp.asarray(batch.T))
+            basis0 = _solve_amr2(A0, b0, c0, None, None)[4]
+    return A, b, c, basis0, rows
+
+
+def _solve_amr2(A, b, c, basis0, col_rows):
+    from repro.core.lp import _bucket_maxiter, simplex_batch_core
+    from repro.core.types import x64_scope
+    with x64_scope():
+        return [np.asarray(o) for o in simplex_batch_core(
+            A, b, c, basis0, nv=A.shape[2] - 2, method="revised",
+            maxiter=_bucket_maxiter(50 * (A.shape[1] + 2)),
+            col_rows=col_rows)]
+
+
+_AMR2_CASES = ["cold", "masked", "es_disabled", "warm"]
+
+
+@pytest.mark.parametrize("case", _AMR2_CASES)
+def test_column_pricer_matches_dense(case):
+    """On the engine's LPs the column pricer and column FTRAN match the
+    dense `price_reduced_ref` / ``Binv @ A_j`` to 1 ulp, at the cold and
+    the optimal factors of every lane, and the whole revised solve keeps
+    its statuses, pivot counts and bases, with x / fun to 1e-12."""
+    import jax.numpy as jnp
+    from repro.core.lp import _column_form, _warm_init_reduced
+    from repro.core.types import x64_scope
+    from repro.kernels.simplex_pivot.ref import (ftran_columns_ref,
+                                                price_columns_ref,
+                                                price_reduced_ref)
+    A, b, c, basis0, rows = _amr2_fleet_lps(case)
+    dense = _solve_amr2(A, b, c, basis0, None)
+    cols_ = _solve_amr2(A, b, c, basis0, rows)
+    x, fun, status, niter, basis, ok = dense
+    assert (status == OPTIMAL).all()
+    assert case != "warm" or ok.all()
+    np.testing.assert_array_equal(cols_[2], status)
+    np.testing.assert_array_equal(cols_[3], niter)
+    np.testing.assert_array_equal(cols_[5], ok)
+    np.testing.assert_array_equal(np.sort(cols_[4], 1), np.sort(basis, 1))
+    np.testing.assert_allclose(cols_[0], x, rtol=0, atol=1e-12)
+    np.testing.assert_allclose(cols_[1], fun, rtol=0, atol=1e-12)
+
+    B, R, C0 = A.shape
+    with x64_scope():
+        cols = _column_form(A, rows)
+        eye = jnp.broadcast_to(jnp.eye(R), (B, R, R))
+        cold_bas = jnp.broadcast_to(C0 + jnp.arange(R), (B, R))
+        Binv_opt, _rhs, bas_opt, _ok = _warm_init_reduced(
+            A, b, jnp.asarray(basis))
+        zero = jnp.zeros_like(c)
+        for Binv, bas in ((eye, cold_bas), (Binv_opt, bas_opt)):
+            # phase 1 prices real columns at 0, so rc = -y·A exactly
+            got = price_columns_ref(cols, zero, Binv, bas, 1.0)
+            want = price_reduced_ref(A, zero, Binv, bas, 1.0)
+            np.testing.assert_array_max_ulp(np.asarray(got),
+                                            np.asarray(want), maxulp=1)
+            for j in range(C0):
+                jj = jnp.full(B, j, jnp.int32)
+                got = ftran_columns_ref(cols, Binv, jj)
+                want = jnp.einsum("brk,bk->br", Binv, A[:, :, j])
+                np.testing.assert_array_max_ulp(np.asarray(got),
+                                                np.asarray(want), maxulp=1)
+
+
+def test_lp_column_rows_scatter_back_to_A():
+    """The static column form of `amr2.lp_column_rows`, with its values
+    gathered from `build_lp_arrays_jnp`'s A, scatters back to A exactly:
+    two distinct rows per column, every other entry of A zero."""
+    import jax.numpy as jnp
+    from repro.core.amr2 import build_lp_arrays_jnp, lp_column_rows
+    from repro.core.lp import _column_form
+    from repro.core.types import x64_scope
+    rng = np.random.default_rng(5)
+    for n, m in [(1, 1), (3, 2), (12, 2), (5, 4), (7, 1)]:
+        B = 3
+        rows = lp_column_rows(n, m)
+        C0 = n * (m + 1) + 2
+        assert rows.shape == (2, C0) and (rows[0] != rows[1]).all()
+        with x64_scope():
+            A, _b, _c = build_lp_arrays_jnp(
+                jnp.asarray(rng.uniform(0.01, 1, (B, n, m))),
+                jnp.asarray(rng.uniform(0.01, 1, (B, n))),
+                jnp.asarray(np.sort(rng.uniform(0.3, 0.9, (B, m + 1)), 1)),
+                jnp.full(B, 1.2))
+            ra, rb, va, vb = (np.asarray(v) for v in _column_form(A, rows))
+        back = np.zeros(A.shape)
+        cidx = np.arange(C0)
+        back[:, ra, cidx] += va
+        back[:, rb, cidx] += vb
+        np.testing.assert_array_equal(back, np.asarray(A))
+        with pytest.raises(ValueError, match="col_rows"):
+            _column_form(A, rows[:, :-1])
+
+
+def test_col_rows_needs_revised_jnp():
+    from repro.core.lp import simplex_batch_core
+    A, b, c, _basis0, rows = _amr2_fleet_lps("cold", B=2, n=2)
+    for kw in (dict(method="tableau"), dict(method="revised",
+                                            impl="pallas")):
+        with pytest.raises(ValueError, match="col_rows"):
+            simplex_batch_core(A, b, c, None, nv=A.shape[2] - 2, maxiter=8,
+                               col_rows=rows, **kw)
+
+
 def test_simplex_batch_core_unknown_method_raises():
     from repro.core.lp import simplex_batch_core
     c, A_ub, b_ub, A_eq, b_eq = _batch_lp(0, nb=2)

@@ -93,7 +93,9 @@ def test_reduced_pivot_compiles_for_v5e(one_chip, x64):
 
 def test_engine_period_step_compiles_for_v5e(one_chip):
     """The engine's jitted float64 period step (the body `rollout` scans)
-    at 64 devices on the reduced-tableau simplex, as the chip runs it."""
+    at 64 devices on the reduced-tableau simplex, as the chip runs it.
+    Its pivot loops price from the LP's column form: no op comes from the
+    dense pricing or FTRAN einsums, only BTRAN's ``cB·Binv``."""
     from repro.api import engine as E
     from repro.serving import FleetConfig
     cfg = FleetConfig(n_devices=64, T=1.2, n_servers=4, policy="amr2",
@@ -106,6 +108,9 @@ def test_engine_period_step_compiles_for_v5e(one_chip):
     with x64_scope():
         compiled = E._step_jit.lower(*shapes).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+    hlo = compiled.as_text()
+    assert "/br,brk->bk/" in hlo
+    assert "/bk,bkc->bc/" not in hlo and "/brk,bk->br/" not in hlo
 
 
 def _wide_call(kernel):
